@@ -41,7 +41,9 @@ SEARCH_BACKENDS = ("sa", "genetic", "evolution", "sobol")
 PORTFOLIO_ALLOCATORS = ("halving", "bandit")
 
 
-def _jobs(macro):
+def fig7_jobs(macro):
+    """The 28 Fig. 7 jobs (seven networks x {so, st} x {ee, th}, 5 mm^2)
+    and their (network, strategy set, objective) metas."""
     jobs, meta = [], []
     for name in SEVEN_WORKLOADS:
         wl = get_workload(name)
@@ -91,7 +93,7 @@ def run() -> typing.Iterator[str]:
     macro = get_macro("vanilla-dcim")
     svc = ServiceClient(engine=ExplorationEngine())
     try:
-        jobs, meta = _jobs(macro)
+        jobs, meta = fig7_jobs(macro)
         t0 = time.perf_counter()
         futures = svc.submit_many(jobs, method="exhaustive", metas=meta)
 

@@ -1,90 +1,30 @@
-"""JAX version-compatibility shims.
+"""JAX API shims, resolved once for the installed JAX series (0.9).
 
-The repo targets a range of JAX releases; a handful of APIs moved between
-them.  Everything version-dependent is resolved here once so the rest of the
-codebase (and the tests) can import stable names:
+The rest of the codebase (and the tests) import these stable names:
 
-* ``enable_x64`` -- the x64 context manager.  ``jax.experimental.enable_x64``
-  is the long-stable spelling; newer releases re-export it at top level.
-  Falls back to a config-flipping context manager if neither exists.
-* ``make_mesh(shape, axis_names)`` -- ``jax.make_mesh`` grew an
-  ``axis_types`` kwarg (``jax.sharding.AxisType``) in newer releases; on
-  older ones the kwarg (and the enum) don't exist.  We always request
-  ``Auto`` axes when the enum is available, which matches the legacy default.
-* ``shard_map`` -- promoted from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``.
-* ``compiled_cost_analysis`` -- ``Compiled.cost_analysis()`` returned a
-  one-element list of dicts before returning a flat dict.
+* ``enable_x64`` -- the x64 context manager (``jax.enable_x64``).
+* ``make_mesh(shape, axis_names)`` -- ``jax.make_mesh`` with ``Auto``
+  axis types on every axis.
+* ``shard_map`` -- ``jax.shard_map``.
+* ``compiled_cost_analysis`` -- ``Compiled.cost_analysis()`` as a plain
+  dict.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
-# --------------------------------------------------------------------- #
-# x64 context manager
-# --------------------------------------------------------------------- #
-if hasattr(jax, "enable_x64"):                      # jax >= 0.5-ish
-    enable_x64 = jax.enable_x64
-else:
-    try:
-        from jax.experimental import enable_x64     # 0.4.x spelling
-    except ImportError:                             # pragma: no cover
-        @contextlib.contextmanager
-        def enable_x64(new_val: bool = True):
-            old = jax.config.jax_enable_x64
-            jax.config.update("jax_enable_x64", bool(new_val))
-            try:
-                yield
-            finally:
-                jax.config.update("jax_enable_x64", old)
-
-
-# --------------------------------------------------------------------- #
-# mesh construction (AxisType appeared in jax.sharding later)
-# --------------------------------------------------------------------- #
-try:
-    from jax.sharding import AxisType               # newer jax
-except ImportError:                                 # older jax: no enum
-    AxisType = None
+enable_x64 = jax.enable_x64
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with ``Auto`` axis types where supported."""
-    kw = {"devices": devices} if devices is not None else {}
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(AxisType.Auto,) * len(axis_names), **kw)
-        except TypeError:                           # pragma: no cover
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    # pre-0.4.35 fallback: hand-build the Mesh          # pragma: no cover
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                         devices=devices)
-    return Mesh(devs, tuple(axis_names))
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
-# --------------------------------------------------------------------- #
-# shard_map
-# --------------------------------------------------------------------- #
-if hasattr(jax, "shard_map"):                       # newer jax
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-# --------------------------------------------------------------------- #
-# Compiled.cost_analysis() normalization
-# --------------------------------------------------------------------- #
 def compiled_cost_analysis(compiled) -> dict:
-    """Flat cost-analysis dict across jax versions (list-of-dicts vs dict)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """Cost analysis of a compiled executable as a flat dict."""
+    return dict(compiled.cost_analysis())

@@ -193,25 +193,35 @@ def calibration_version(
 # --------------------------------------------------------------------- #
 # the fitting pass
 # --------------------------------------------------------------------- #
-def _features(record: typing.Mapping) -> tuple[float, float] | None:
+def _peaks(peaks):
+    """``peaks``, or the attached device's published ones (raises
+    ``UnknownDeviceError`` for a device kind without an entry)."""
+    if peaks is not None:
+        return peaks
+    from repro.obs import profile as _profile
+
+    return _profile.device_peaks()
+
+
+def _features(record: typing.Mapping,
+              peaks) -> tuple[float, float] | None:
     """(compute_us, memory_us) roofline features of one measurement
-    record, or ``None`` when the record carries no cost analysis."""
+    record against ``peaks`` (a ``repro.obs.profile.DevicePeaks``), or
+    ``None`` when the record carries no cost analysis."""
     flops = record.get("flops")
     nbytes = record.get("bytes")
     if not flops and not nbytes:
         return None
-    from repro.obs import profile as _profile
-
-    t_c = float(flops or 0.0) / _profile.peak_flops() * 1e6
-    t_m = float(nbytes or 0.0) / _profile.peak_bw() * 1e6
+    t_c = float(flops or 0.0) / peaks.flops * 1e6
+    t_m = float(nbytes or 0.0) / peaks.bw * 1e6
     return t_c, t_m
 
 
-def _usable(records: typing.Iterable[typing.Mapping]) -> list[tuple[
-        float, float, float]]:
+def _usable(records: typing.Iterable[typing.Mapping],
+            peaks) -> list[tuple[float, float, float]]:
     rows = []
     for r in records:
-        feats = _features(r)
+        feats = _features(r, peaks)
         if feats is None or r.get("us") is None:
             continue
         rows.append((feats[0], feats[1], float(r["us"])))
@@ -229,6 +239,7 @@ def _clamp(x: float) -> float:
 
 def fit_corrections(
     records: typing.Sequence[typing.Mapping],
+    peaks=None,
 ) -> CorrectionFactors:
     """Least-squares fit of :class:`CorrectionFactors` from measurement
     records (the :class:`repro.obs.profile.MeasurementRecord` schema:
@@ -239,9 +250,11 @@ def fit_corrections(
     1-D fits.  Factors are clamped to ``[1e-3, 1e3]``; ``update`` follows
     ``memory`` (CIM updates are write traffic) and ``leakage`` stays 1.0.
     Raises ``ValueError`` when no record carries both a timing and a cost
-    analysis.
+    analysis.  ``peaks`` (a ``repro.obs.profile.DevicePeaks``) defaults to
+    the attached device's published peaks.
     """
-    rows = _usable(records)
+    peaks = _peaks(peaks)
+    rows = _usable(records, peaks)
     if not rows:
         raise ValueError(
             "no usable measurement records (need 'us' plus a "
@@ -265,15 +278,16 @@ def fit_corrections(
         CorrectionFactors(), compute=compute, memory=memory, update=memory,
         fitted_on=len(rows))
     return dataclasses.replace(
-        fitted, residual_us=evaluate_corrections(records, fitted))
+        fitted, residual_us=evaluate_corrections(records, fitted, peaks))
 
 
 def predict_us(record: typing.Mapping,
-               corrections: CorrectionFactors | None = None) -> float | None:
+               corrections: CorrectionFactors | None = None,
+               peaks=None) -> float | None:
     """Model-predicted kernel time (us) for one measurement record;
     ``None`` when the record has no cost analysis.  ``corrections=None``
     is the *uncalibrated* roofline prediction (both factors 1.0)."""
-    feats = _features(record)
+    feats = _features(record, _peaks(peaks))
     if feats is None:
         return None
     c = corrections or CorrectionFactors()
@@ -283,10 +297,11 @@ def predict_us(record: typing.Mapping,
 def evaluate_corrections(
     records: typing.Sequence[typing.Mapping],
     corrections: CorrectionFactors | None = None,
+    peaks=None,
 ) -> float:
     """RMS error (us) of the (possibly uncalibrated) model over the
     records' measured timings."""
-    rows = _usable(records)
+    rows = _usable(records, _peaks(peaks))
     if not rows:
         raise ValueError("no usable measurement records to evaluate")
     c = corrections or CorrectionFactors()
@@ -301,6 +316,7 @@ def fit_report(
     records: typing.Sequence[typing.Mapping],
     holdout_fraction: float = 0.25,
     seed: int = 0,
+    peaks=None,
 ) -> dict:
     """Fit on a deterministic train split, score on the held-out rest.
 
@@ -315,8 +331,9 @@ def fit_report(
     same records, so ``improvement > 1`` means the fit generalizes.  With
     fewer than 3 usable records the whole set is both train and holdout.
     """
+    peaks = _peaks(peaks)
     usable = [r for r in records
-              if _features(r) is not None and r.get("us") is not None]
+              if _features(r, peaks) is not None and r.get("us") is not None]
     if not usable:
         raise ValueError("no usable measurement records to fit")
     order = list(range(len(usable)))
@@ -329,9 +346,9 @@ def fit_report(
         hold_ix = set(order[:n_hold])
         train = [r for i, r in enumerate(usable) if i not in hold_ix]
         holdout = [r for i, r in enumerate(usable) if i in hold_ix]
-    corrections = fit_corrections(train)
-    uncal = evaluate_corrections(holdout)
-    cal = evaluate_corrections(holdout, corrections)
+    corrections = fit_corrections(train, peaks)
+    uncal = evaluate_corrections(holdout, None, peaks)
+    cal = evaluate_corrections(holdout, corrections, peaks)
     return {
         "corrections": corrections.as_dict(),
         "version": calibration_version(corrections),

@@ -44,6 +44,7 @@ import typing
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.calibration import TechConstants, resolve_tech
 from repro.core.macro import MacroSpec
@@ -152,12 +153,29 @@ def _score(lat, en, code):
                      jnp.where(code == OBJ_CODES["edp"], lat * en, en))
 
 
+# The TPU's float32 divide is not correctly rounded: 21 / 7 comes out as
+# 3.0000002, so ceil(a / b) lands one step high on an exact quotient (and
+# floor one step low).  One multiply-and-compare step on each side makes
+# both exact for integer operands below 2**24; where the divide is
+# correctly rounded (the CPU) they change nothing.
+def _snap_ceil(q, a, b):
+    """``q`` = ceil of an approximate ``a / b`` -> the exact ceiling."""
+    q = jnp.where((q - 1.0) * b >= a, q - 1.0, q)
+    return jnp.where(q * b < a, q + 1.0, q)
+
+
+def _snap_floor(q, a, b):
+    """``q`` = floor of an approximate ``a / b`` -> the exact floor."""
+    q = jnp.where(q * b > a, q - 1.0, q)
+    return jnp.where((q + 1.0) * b <= a, q + 1.0, q)
+
+
 def _ceil(a, b):
-    return jnp.ceil(a / b)
+    return _snap_ceil(jnp.ceil(a / b), a, b)
 
 
 def _fdiv(a, b):
-    return jnp.floor(a / b)
+    return _snap_floor(jnp.floor(a / b), a, b)
 
 
 class CostBreakdown(typing.NamedTuple):
@@ -363,10 +381,12 @@ def matmul_cost(
 # ---------------------------------------------------------------------- #
 # vectorized stacks
 # ---------------------------------------------------------------------- #
-_STRAT_BITS = jnp.array(
+#: [8, 3] strategy bits (reversed, weight priority, parallel first).  A
+#: numpy constant, so importing this module starts no JAX backend; traced
+#: code converts it where it is used.
+_STRAT_BITS = np.array(
     [[float(s.spatial == "R"), float(s.temporal == "WP"),
-      float(s.tiling == "PF")] for s in ALL_STRATEGIES]
-)  # [8, 3]
+      float(s.tiling == "PF")] for s in ALL_STRATEGIES], np.float32)
 
 
 def strategy_table(op_row, cfg_row, area_mm2, macro, tech=None):
@@ -426,8 +446,17 @@ def workload_cost_core(
         tbl = jax.vmap(_one)(strat_bits)
         lat = jnp.where(allowed > 0, tbl.latency_cycles, INFEASIBLE)
         en = jnp.where(allowed > 0, tbl.energy_pj, INFEASIBLE)
-        idx = jnp.argmin(_score(lat, en, code))
-        return lat[idx], en[idx], idx
+        # argmin and its selection spelled as compare/where/reduce over the
+        # 8 strategies: the TPU kernel compiler lowers no gather under a
+        # vmap.  First index of the minimum (argmin's tie rule); summing
+        # one picked value with zeros is exact, so results are unchanged.
+        score = _score(lat, en, code)
+        lanes = jnp.arange(score.shape[0])
+        idx = jnp.min(jnp.where(score == jnp.min(score), lanes,
+                                score.shape[0]))
+        pick = lanes == idx
+        return (jnp.sum(jnp.where(pick, lat, 0.0)),
+                jnp.sum(jnp.where(pick, en, 0.0)), idx)
 
     lat, en, idx = jax.vmap(per_op)(ops_arr)
     counts = ops_arr[:, 3]

@@ -49,6 +49,7 @@ shards the same job x chain population across devices.
 """
 from __future__ import annotations
 
+import collections
 import copy
 import dataclasses
 import hashlib
@@ -135,32 +136,36 @@ for _m in (_M_SCHED_RELEASED, _M_SCHED_ABSORBED, _M_SCHED_FLATLINED):
 # --------------------------------------------------------------------- #
 _persistent_cache_dir: str | None = None
 
+#: the cache directory when ``JAX_COMPILATION_CACHE_DIR`` is not set: a
+#: fixed path inside the checkout, so every process of one command (and
+#: the next command on the same checkout) shares it
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax-compile-cache")
 
-def enable_persistent_compilation_cache(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at a writable directory.
+
+def enable_persistent_compilation_cache() -> str | None:
+    """Switch on JAX's persistent compilation cache for this process.
 
     On by default for every :class:`ExplorationEngine` so benchmark and CI
-    processes reuse each other's compiles.  Respects an operator-provided
-    ``JAX_COMPILATION_CACHE_DIR``/pre-set config; set
-    ``CIM_TUNER_DISABLE_PERSISTENT_CACHE=1`` to opt out.  Returns the active
-    cache directory (or ``None`` when disabled).
+    processes reuse each other's compiles.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and nothing
+    is set here; otherwise the cache lives at :data:`CHECKOUT_CACHE_DIR`.
+    ``CIM_TUNER_DISABLE_PERSISTENT_CACHE=1`` opts out.  Returns the active
+    cache directory (``None`` when disabled or when the directory cannot
+    be used, which is logged).
     """
     global _persistent_cache_dir
     if os.environ.get("CIM_TUNER_DISABLE_PERSISTENT_CACHE"):
         return None
-    current = jax.config.jax_compilation_cache_dir
-    if current:
-        _persistent_cache_dir = current
-        return current
-    path = (
-        path
-        or os.environ.get("CIM_TUNER_COMPILE_CACHE")
-        or os.path.join(
-            os.path.expanduser("~"), ".cache", "cim-tuner", "jax-cache")
-    )
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _persistent_cache_dir = jax.config.jax_compilation_cache_dir
+        return _persistent_cache_dir
+    if jax.config.jax_compilation_cache_dir == CHECKOUT_CACHE_DIR:
+        return _persistent_cache_dir
     try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        os.makedirs(CHECKOUT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
         # our SA executables compile in O(1s); make sure they qualify
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
         # JAX latches "cache disabled" at its FIRST compile (tiny ops fire
@@ -170,10 +175,12 @@ def enable_persistent_compilation_cache(path: str | None = None) -> str | None:
             compilation_cache as jax_cc,
         )
         jax_cc.reset_cache()
-    except Exception:                                  # pragma: no cover
-        return None                                    # read-only FS etc.
-    _persistent_cache_dir = path
-    return path
+    except OSError as exc:
+        _LOG.warning("persistent compilation cache off: cannot use %s: %s",
+                     CHECKOUT_CACHE_DIR, exc)
+        return None
+    _persistent_cache_dir = CHECKOUT_CACHE_DIR
+    return _persistent_cache_dir
 
 
 # --------------------------------------------------------------------- #
@@ -473,6 +480,9 @@ class ExplorationEngine:
             "executable_cache_misses": _M_EXEC.labels(outcome="miss"),
             "device_race_dispatches": _M_RACE.labels(),
         })
+        #: device_race_dispatches per device the dispatch ran on
+        self.race_dispatch_devices: collections.Counter = \
+            collections.Counter()
         if persistent_compile_cache:
             enable_persistent_compilation_cache()
 
@@ -484,6 +494,8 @@ class ExplorationEngine:
             **self.stats.snapshot(),
             "executable_cache_size": len(self._executables),
             "persistent_compile_cache": _persistent_cache_dir,
+            "device_race_dispatches_by_device":
+                dict(self.race_dispatch_devices),
         }
 
     # ------------------------------------------------------------- #
@@ -511,6 +523,7 @@ class ExplorationEngine:
                 return out
             return fn(*a, **kw)
 
+        wrapper.__wrapped__ = fn         # the jitted callable (``.lower``)
         return wrapper
 
     def _cached(self, key, build):
@@ -872,10 +885,14 @@ class ExplorationEngine:
             backend, batch[0].ops_pad, axes_pad, settings)
         operands = (stacked, jnp.asarray(mats), jnp.asarray(lens),
                     jnp.asarray(keys))
-        if device is not None:
-            operands = jax.device_put(operands, device)
-            self.stats.bump("device_race_dispatches")
-        return fn(*operands)
+        if device is None:
+            return fn(*operands)
+        out = fn(*jax.device_put(operands, device))
+        self.stats.bump("device_race_dispatches")
+        # where the executable really ran: the device its outputs live on
+        for d in out[0].devices():
+            self.race_dispatch_devices[str(d)] += 1
+        return out
 
     def _dispatch_backend(
         self, batch: list[_PreparedJob], backend, settings,

@@ -50,12 +50,16 @@ def _kernel_af(a_ref, b_ref, o_ref, acc_ref, *, n_contract: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _kernel_pf(a_ref, b_ref, o_ref):
+def _kernel_pf(a_ref, b_ref, o_ref, psum_ref):
     """PF body: N innermost; the input block stays VMEM-resident while the
-    output tile is read-modify-written across the K grid axis -- the psum
-    HBM round-trips that CIM-Tuner charges the PF strategy (paper Fig. 8).
-    Accumulation happens at the output dtype, mirroring dw_psum."""
+    output tiles of its row are revisited across the K grid axis.  The
+    running partial sums live in a VMEM row panel (``psum_ref``, one tile
+    per N block -- the TPU never reads an output block back from HBM), and
+    every step writes its tile's partial sum out: the psum HBM traffic
+    CIM-Tuner charges the PF strategy (paper Fig. 8).  Accumulation happens
+    at the output dtype, mirroring dw_psum."""
     step = pl.program_id(1)
+    j = pl.program_id(2)
     partial_ = jnp.dot(
         a_ref[...].astype(jnp.float32),
         b_ref[...].astype(jnp.float32),
@@ -64,11 +68,13 @@ def _kernel_pf(a_ref, b_ref, o_ref):
 
     @pl.when(step == 0)
     def _init():
-        o_ref[...] = partial_
+        psum_ref[j] = partial_
 
     @pl.when(step > 0)
-    def _rmw():
-        o_ref[...] += partial_
+    def _accumulate():
+        psum_ref[j] = psum_ref[j] + partial_
+
+    o_ref[...] = psum_ref[j]
 
 
 def cim_matmul(
@@ -106,7 +112,7 @@ def cim_matmul(
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
             out_shape=jax.ShapeDtypeStruct(
                 (a.shape[0], b.shape[1]), out_dtype),
-            scratch_shapes=[_vmem_scratch((bm, bn))],
+            scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
             interpret=interpret,
         )(a, b)
     elif tiling == "PF":
@@ -122,6 +128,7 @@ def cim_matmul(
             out_specs=pl.BlockSpec((bm, bn), lambda i, s, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct(
                 (a.shape[0], b.shape[1]), out_dtype),
+            scratch_shapes=[_vmem_scratch((gn, bm, bn), out_dtype)],
             interpret=interpret,
         )(a, b)
     else:
@@ -129,7 +136,7 @@ def cim_matmul(
     return out[:m, :n]
 
 
-def _vmem_scratch(shape):
-    """f32 VMEM accumulator tile (the psum register of the CIM analogy)."""
+def _vmem_scratch(shape, dtype):
+    """VMEM accumulator (the psum register of the CIM analogy)."""
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, jnp.float32)
+    return pltpu.VMEM(shape, dtype)

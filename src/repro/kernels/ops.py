@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU hosts (the TPU custom-call path can't
-compile here); on a TPU runtime pass interpret=False (or set
-REPRO_PALLAS_COMPILE=1) for the real kernels.
+``interpret=None`` picks the mode from the default JAX backend: the
+compiled Mosaic kernels on a TPU, the Pallas interpreter on the CPU (where
+the tests run), and an error on any other backend -- a kernel never runs
+interpreted on an accelerator by accident.
 
 With ``CIM_TUNER_PROFILE`` set, every call is timed to completion and
 recorded into the ``cim_kernel_*`` metric families per (kernel, shape
@@ -10,7 +11,6 @@ bucket) -- see ``repro.obs.profile``.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -24,9 +24,14 @@ from repro.obs import profile as _profile
 
 
 def _default_interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE"):
+    platform = jax.default_backend()
+    if platform == "tpu":
         return False
-    return jax.default_backend() != "tpu"
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and interpret on CPU only; the "
+        f"default JAX backend is {platform!r}")
 
 
 @partial(jax.jit, static_argnames=("tiling", "bm", "bn", "bk", "interpret"))

@@ -32,14 +32,13 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def strategy_eval_ref(candidates, ops_arr, macro, *, objective="ee",
                       strategy_set="st", tech=None):
-    """Identical math to the kernel, no pallas_call."""
-    tech = resolve_tech(tech)
-    from repro.kernels.strategy_eval import _objective_block, _strat_tables
-    bits, allowed = _strat_tables(strategy_set)
-    return _objective_block(
-        jnp.asarray(candidates, jnp.float32),
-        jnp.asarray(ops_arr, jnp.float32),
-        jnp.asarray(bits), jnp.asarray(allowed), macro, tech, objective)
+    """The engine's own objective (no area budget), vmapped over the
+    candidates."""
+    from repro.core import cost_model
+    fn = cost_model.make_objective_fn(
+        jnp.asarray(ops_arr, jnp.float32), macro, resolve_tech(tech),
+        objective, strategy_set)
+    return jax.vmap(fn)(jnp.asarray(candidates, jnp.float32))
 
 
 def selective_scan_ref(xi, dt, bmat, cmat, a, h0, chunk: int = 64):

@@ -2,17 +2,21 @@
 the roofline finding that mamba prefill/train is bound by materializing
 [B, T, I, S] recurrence coefficients in HBM (EXPERIMENTS Perf cell B).
 
-Layout: grid (B, I_tiles, T_chunks), T innermost.  The hidden state
-h [I_TILE, S] lives in VMEM scratch for the *entire* sequence of one
-(batch, channel-tile): coefficients da = exp(dt*a) and dbx = dt*B*x are
-computed on the fly from the [CT, I_TILE] / [CT, S] chunk inputs and never
-touch HBM.  HBM traffic is exactly inputs (xi, dt, b, c) + outputs (y) --
-the information-theoretic minimum -- versus the jnp path's
-O(T*I*S)-per-level associative-scan materializations.
+Layout: grid (B, I_tiles, T_chunks), T innermost.  The hidden state is
+held transposed, h [S, I_TILE] with channels on the lanes, in VMEM scratch
+for the *entire* sequence of one (batch, channel-tile): coefficients
+da = exp(dt*a) and dbx = dt*B*x are computed on the fly from the
+[CT, I_TILE] / [CT, S] chunk inputs and never touch HBM.  HBM traffic is
+exactly inputs (xi, dt, b, c) + outputs (y) -- the information-theoretic
+minimum -- versus the jnp path's O(T*I*S)-per-level associative-scan
+materializations.
 
 The recurrence is sequential over time inside the chunk (lax.fori_loop on
-[I_TILE, S] VPU ops); TPU grid steps along the last axis are sequential, so
-the scratch legally carries state across T-chunks.
+[S, I_TILE] VPU ops).  Each step reads its timestep's rows straight from
+the input refs and writes its output row into the output ref (``pl.ds``);
+the B and C rows turn into [S, 1] columns by a masked lane reduction, so
+no transpose runs in the kernel.  TPU grid steps along the last axis are
+sequential, so the scratch legally carries state across T-chunks.
 """
 from __future__ import annotations
 
@@ -26,33 +30,37 @@ DEFAULT_CT = 128       # timesteps per grid step
 DEFAULT_CI = 256       # channel tile
 
 
+def _column(row, eye):
+    """[1, S] row -> [S, 1] column (masked lane reduction)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
 def _kernel(xi_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref,
             y_ref, hlast_ref, h_ref, *, n_tchunks: int, ct: int):
     t_step = pl.program_id(2)
 
     @pl.when(t_step == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)       # [CI, S]
+        h_ref[...] = h0_ref[0].astype(jnp.float32)       # [S, CI]
 
-    a = a_ref[...].astype(jnp.float32)                   # [CI, S]
-    xi = xi_ref[0].astype(jnp.float32)                   # [CT, CI]
-    dt = dt_ref[0].astype(jnp.float32)                   # [CT, CI]
-    bm = b_ref[0].astype(jnp.float32)                    # [CT, S]
-    cm = c_ref[0].astype(jnp.float32)                    # [CT, S]
+    a = a_ref[...].astype(jnp.float32)                   # [S, CI]
+    s = a.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (s, s), 1))
 
-    def step(t, carry):
-        h, y = carry
-        da = jnp.exp(dt[t][:, None] * a)                 # [CI, S]
-        dbx = (dt[t] * xi[t])[:, None] * bm[t][None, :]  # [CI, S]
-        h = da * h + dbx
-        y = y.at[t].set(h @ cm[t])                       # [CI]
-        return h, y
+    def step(t, h):
+        row = pl.ds(t, 1)
+        dt = dt_ref[0, row, :].astype(jnp.float32)       # [1, CI]
+        xi = xi_ref[0, row, :].astype(jnp.float32)       # [1, CI]
+        bm = _column(b_ref[0, row, :].astype(jnp.float32), eye)  # [S, 1]
+        cm = _column(c_ref[0, row, :].astype(jnp.float32), eye)  # [S, 1]
+        h = jnp.exp(dt * a) * h + bm * (dt * xi)         # [S, CI]
+        y_ref[0, row, :] = jnp.sum(h * cm, axis=0,
+                                   keepdims=True).astype(y_ref.dtype)
+        return h
 
-    h0 = h_ref[...]
-    y0 = jnp.zeros((ct, xi.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, ct, step, (h0, y0))
+    h = jax.lax.fori_loop(0, ct, step, h_ref[...])
     h_ref[...] = h
-    y_ref[0, ...] = y.astype(y_ref.dtype)
 
     @pl.when(t_step == n_tchunks - 1)
     def _emit_state():
@@ -99,21 +107,21 @@ def selective_scan(
             pl.BlockSpec((1, ct, ci), lambda bb, ii, tt: (bb, tt, ii)),  # dt
             pl.BlockSpec((1, ct, s), lambda bb, ii, tt: (bb, tt, 0)),    # b
             pl.BlockSpec((1, ct, s), lambda bb, ii, tt: (bb, tt, 0)),    # c
-            pl.BlockSpec((ci, s), lambda bb, ii, tt: (ii, 0)),           # a
-            pl.BlockSpec((1, ci, s), lambda bb, ii, tt: (bb, ii, 0)),    # h0
+            pl.BlockSpec((s, ci), lambda bb, ii, tt: (0, ii)),           # a^T
+            pl.BlockSpec((1, s, ci), lambda bb, ii, tt: (bb, 0, ii)),    # h0^T
         ],
         out_specs=[
             pl.BlockSpec((1, ct, ci), lambda bb, ii, tt: (bb, tt, ii)),  # y
-            pl.BlockSpec((1, ci, s), lambda bb, ii, tt: (bb, ii, 0)),    # h
+            pl.BlockSpec((1, s, ci), lambda bb, ii, tt: (bb, 0, ii)),    # h^T
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, tp, ip), xi.dtype),
-            jax.ShapeDtypeStruct((b, ip, s), h0.dtype),
+            jax.ShapeDtypeStruct((b, s, ip), h0.dtype),
         ],
-        scratch_shapes=[_vmem((ci, s), jnp.float32)],
+        scratch_shapes=[_vmem((s, ci), jnp.float32)],
         interpret=interpret,
-    )(xi, dt, bmat, cmat, a, h0)
-    return y[:, :t, :i], hlast[:, :i]
+    )(xi, dt, bmat, cmat, a.T, jnp.swapaxes(h0, 1, 2))
+    return y[:, :t, :i], jnp.swapaxes(hlast, 1, 2)[:, :i]
 
 
 def _vmem(shape, dtype):

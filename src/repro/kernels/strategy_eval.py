@@ -1,12 +1,14 @@
 """Batched CIM-Tuner cost-model evaluation as a Pallas VPU kernel.
 
 The DSE hot loop evaluates candidates x operators x 8 strategies of pure
-elementwise arithmetic -- bandwidth-light, VPU-bound.  This kernel tiles the
-candidate axis into VMEM blocks and reuses the *same* closed-form cost model
-(``core.cost_model.workload_cost_core``) inside the kernel body, so kernel
-and oracle can never drift: ref.py is the identical computation without
-pallas_call.  The strategy-bit and mask tables are kernel operands (Pallas
-kernels may not capture array constants).
+elementwise arithmetic -- bandwidth-light, VPU-bound.  This kernel puts the
+candidate axis on the TPU's lanes: each grid step holds a ``[6, T]`` block
+of candidate columns, and every operator's cost table is one ``[8, T]``
+array (strategies on sublanes) built by the same closed-form
+``core.cost_model.matmul_cost`` the engine vmaps.  Operators are read as
+scalars from SMEM inside a ``fori_loop``; the per-operator strategy argmin
+and the count-weighted sums run as sublane reductions.  ``kernels/ref.py``
+checks it against the engine's own objective (``make_objective_fn``).
 """
 from __future__ import annotations
 
@@ -14,45 +16,78 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import cost_model
 from repro.core.calibration import resolve_tech
 from repro.core.macro import MacroSpec
 from repro.core.strategies import ALL_STRATEGIES, STRATEGY_SETS
 
-CAND_TILE = 256
+#: candidates per grid step (the lane axis; a multiple of 128)
+CAND_TILE = 512
 
 
-def _strat_tables(strategy_set: str) -> tuple[np.ndarray, np.ndarray]:
-    bits = np.array(
-        [[float(s.spatial == "R"), float(s.temporal == "WP"),
-          float(s.tiling == "PF")] for s in ALL_STRATEGIES], np.float32)
-    allowed = np.array(
-        [1.0 if s in STRATEGY_SETS[strategy_set] else 0.0
-         for s in ALL_STRATEGIES], np.float32)
-    return bits, allowed
+def _per_strategy(strat, values):
+    """``[8, 1]`` column holding ``values[s]`` in row ``s`` (built from an
+    iota: Pallas kernels may not capture array constants)."""
+    out = jnp.zeros(strat.shape, jnp.float32)
+    for s, v in enumerate(values):
+        out = jnp.where(strat == s, jnp.float32(v), out)
+    return out
 
 
-def _objective_block(cfg_block, ops_arr, bits, allowed, macro, tech,
-                     objective):
-    """[T, 6] candidate block -> [T] best-strategy objective values."""
-    def per_candidate(cfg_row):
-        lat, en, _ = cost_model.workload_cost_core(
-            ops_arr, cfg_row, bits, allowed, macro, tech, objective)
-        val = cost_model.objective_value(lat, en, objective)
-        return jnp.where(
-            cost_model.bandwidth_ok_jnp(cfg_row, macro), val,
-            cost_model.INFEASIBLE)
-    return jax.vmap(per_candidate)(cfg_block)
+def _objective_block(cfg, n_ops, op_at, macro, tech, objective,
+                     strategy_set):
+    """Best-strategy objective of a candidate block.
+
+    ``cfg`` is the six config rows (mr, mc, scr, is_kb, os_kb, bw), each
+    ``[1, T]``; ``op_at(p)`` returns operator ``p``'s (m, k, n, count)
+    scalars.  Returns the ``[1, T]`` objective values."""
+    mp, tp = cost_model._as_params(macro, tech)
+    code = cost_model.objective_code(objective)
+    area = cost_model.area_mm2_jnp(cfg, mp, tp)
+    n_strat = len(ALL_STRATEGIES)
+    strat = jax.lax.broadcasted_iota(jnp.int32, (n_strat, 1), 0)
+    rev = _per_strategy(strat, [s.spatial == "R" for s in ALL_STRATEGIES])
+    wp = _per_strategy(strat, [s.temporal == "WP" for s in ALL_STRATEGIES])
+    pf = _per_strategy(strat, [s.tiling == "PF" for s in ALL_STRATEGIES])
+    allowed = _per_strategy(
+        strat, [s in STRATEGY_SETS[strategy_set] for s in ALL_STRATEGIES])
+
+    def add_op(p, acc):
+        m, k, n, count = op_at(p)
+        tbl = cost_model.matmul_cost(m, k, n, rev, wp, pf, *cfg, area,
+                                     mp, tp)
+        lat = jnp.where(allowed > 0, tbl.latency_cycles,
+                        cost_model.INFEASIBLE)                  # [8, T]
+        en = jnp.where(allowed > 0, tbl.energy_pj, cost_model.INFEASIBLE)
+        score = cost_model._score(lat, en, code)
+        best = jnp.min(score, axis=0, keepdims=True)
+        idx = jnp.min(jnp.where(score == best, strat, n_strat), axis=0,
+                      keepdims=True)
+        pick = strat == idx
+        return (acc[0] + jnp.sum(jnp.where(pick, lat, 0.0), axis=0,
+                                 keepdims=True) * count,
+                acc[1] + jnp.sum(jnp.where(pick, en, 0.0), axis=0,
+                                 keepdims=True) * count)
+
+    zeros = jnp.zeros_like(area)
+    lat, en = jax.lax.fori_loop(0, n_ops, add_op, (zeros, zeros))
+    val = cost_model.objective_value(lat, en, code)
+    return jnp.where(cost_model.bandwidth_ok_jnp(cfg, mp), val,
+                     cost_model.INFEASIBLE)
 
 
-def _kernel(cfg_ref, ops_ref, bits_ref, allowed_ref, o_ref, *, macro, tech,
-            objective):
-    o_ref[...] = _objective_block(
-        cfg_ref[...], ops_ref[...], bits_ref[...], allowed_ref[...],
-        macro, tech, objective).astype(o_ref.dtype)
+def _kernel(cfg_ref, ops_ref, o_ref, *, n_ops, macro, tech, objective,
+            strategy_set):
+    cfg = [cfg_ref[i:i + 1, :] for i in range(6)]
+
+    def op_at(p):
+        return tuple(ops_ref[p, c] for c in range(4))
+
+    o_ref[...] = _objective_block(cfg, n_ops, op_at, macro, tech,
+                                  objective, strategy_set)
 
 
 def strategy_eval(
@@ -66,28 +101,24 @@ def strategy_eval(
     tile: int = CAND_TILE,
     interpret: bool = False,
 ) -> jax.Array:
+    """``[C]`` best-strategy objective of every candidate (no area
+    penalty; bandwidth-infeasible rows are ``INFEASIBLE``)."""
     tech = resolve_tech(tech)
     c = candidates.shape[0]
     pad = (-c) % tile
-    if pad:
-        candidates = jnp.pad(candidates, ((0, pad), (0, 0)),
-                             constant_values=1.0)
-    bits, allowed = _strat_tables(strategy_set)
-    grid = (candidates.shape[0] // tile,)
+    cand_t = jnp.pad(candidates.astype(jnp.float32), ((0, pad), (0, 0)),
+                     constant_values=1.0).T                 # [6, C + pad]
     out = pl.pallas_call(
-        functools.partial(_kernel, macro=macro, tech=tech,
-                          objective=objective),
-        grid=grid,
+        functools.partial(_kernel, n_ops=ops_arr.shape[0], macro=macro,
+                          tech=tech, objective=objective,
+                          strategy_set=strategy_set),
+        grid=(cand_t.shape[1] // tile,),
         in_specs=[
-            pl.BlockSpec((tile, 6), lambda i: (i, 0)),
-            pl.BlockSpec(ops_arr.shape, lambda i: (0, 0)),   # replicated
-            pl.BlockSpec(bits.shape, lambda i: (0, 0)),
-            pl.BlockSpec(allowed.shape, lambda i: (0,)),
+            pl.BlockSpec((6, tile), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # whole, scalar
         ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((candidates.shape[0],),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, cand_t.shape[1]), jnp.float32),
         interpret=interpret,
-    )(candidates.astype(jnp.float32), ops_arr.astype(jnp.float32),
-      jnp.asarray(bits), jnp.asarray(allowed))
-    return out[:c]
+    )(cand_t, ops_arr.astype(jnp.float32))
+    return out[0, :c]

@@ -33,8 +33,13 @@ import json
 import os
 import time
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
+from repro.obs.profile import PEAKS
+
+#: the dry-run projects its cells onto a TPU v5e pod: that chip's
+#: published peaks, from the one device table
+_CHIP = PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _CHIP.flops     # bf16 / chip
+HBM_BW = _CHIP.bw            # bytes/s / chip
 LINK_BW = 50e9               # bytes/s / link
 
 

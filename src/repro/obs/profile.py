@@ -16,10 +16,10 @@ record, per ``(kernel, bucket)`` series:
     substrate the ROADMAP calibration tier fits correction factors from.
 
 Everything is gated on ``CIM_TUNER_PROFILE`` (checked per call, so the
-hooks cost one env lookup when off).  Peak rates default to the TPU v5e
-constants shared with ``repro.launch.roofline`` and can be overridden via
-``CIM_TUNER_PEAK_FLOPS`` / ``CIM_TUNER_PEAK_BW`` (interpret-mode CPU runs
-report honest-but-tiny utilizations against TPU peaks).
+hooks cost one env lookup when off).  Peak rates come from :data:`PEAKS`,
+one table keyed by ``jax.Device.device_kind`` with each entry's source;
+a device kind missing from it (the CPU, where kernels run interpreted)
+gets no roofline gauge, and :func:`device_peaks` raises for it.
 
 This module is a STABLE PUBLIC SURFACE (re-exported from ``repro.obs``):
 :func:`run_microbench` is the measurement half of the calibration tier --
@@ -29,14 +29,14 @@ it times the real Pallas kernels over a small tiling sweep and returns
     {"kernel": str,   # cim_matmul | flash_attention | selective_scan
                       # | strategy_eval
      "bucket": str,   # shape bucket, e.g. "128x128x128"
-     "tiling": str,   # tiling variant, e.g. "AF", "bq64xbk64", "ct16xci16"
+     "tiling": str,   # tiling variant, e.g. "AF", "bq64xbk64", "ct16xci128"
      "us":     float, # one call's wall clock, microseconds
      "flops":  float | None,   # compiled cost analysis (None: unavailable)
      "bytes":  float | None,
      "seed":   int}   # RNG seed the inputs were drawn from
 
 which ``repro.core.calibration.fit_corrections`` consumes.  Names with a
-leading underscore (``_cost_analysis``, ``_env_float``, ...) are
+leading underscore (``_cost_analysis``, ``_device_kind``, ...) are
 implementation details and may change without notice.
 """
 from __future__ import annotations
@@ -56,8 +56,10 @@ __all__ = [
     "profiling_enabled",
     "instrument",
     "roofline_utilization",
-    "peak_flops",
-    "peak_bw",
+    "DevicePeaks",
+    "PEAKS",
+    "UnknownDeviceError",
+    "device_peaks",
     "summary",
     "run_microbench",
     "record_measurements",
@@ -72,10 +74,25 @@ PROFILE_ENV = "CIM_TUNER_PROFILE"
 KERNEL_US_BUCKETS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
                      500.0, 1e3, 2.5e3, 5e3, 1e4, 2.5e4, 1e5, 2.5e5, 1e6)
 
-#: defaults mirror repro.launch.roofline (TPU v5e: bf16 FLOP/s per chip,
-#: HBM bandwidth)
-DEFAULT_PEAK_FLOPS = 197e12
-DEFAULT_PEAK_BW = 819e9
+
+
+class DevicePeaks(typing.NamedTuple):
+    """Published per-chip peaks one roofline is drawn against."""
+
+    flops: float        # bf16 FLOP/s
+    bw: float           # HBM bytes/s
+    source: str
+
+
+#: peaks keyed by ``jax.Device.device_kind``
+PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(197e12, 819e9,
+                               "Google Cloud TPU v5e documentation"),
+}
+
+
+class UnknownDeviceError(LookupError):
+    """A device kind with no entry in :data:`PEAKS`."""
 
 _REG = _metrics.registry()
 _M_US = _REG.histogram(
@@ -107,40 +124,38 @@ def profiling_enabled() -> bool:
     return os.environ.get(PROFILE_ENV, "") not in ("", "0", "false", "no")
 
 
-def _env_float(var: str, default: float) -> float:
-    raw = os.environ.get(var)
+def _device_kind() -> str:
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def device_peaks(device_kind: str | None = None) -> DevicePeaks:
+    """Peaks of ``device_kind`` (default: the first visible JAX device's).
+    Raises :class:`UnknownDeviceError` for a kind not in :data:`PEAKS`."""
+    kind = _device_kind() if device_kind is None else device_kind
     try:
-        return float(raw) if raw else default
-    except ValueError:
-        return default
+        return PEAKS[kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peaks for device kind {kind!r} "
+            f"(known: {sorted(PEAKS)})") from None
 
 
-def peak_flops() -> float:
-    """Peak FLOP/s the roofline is drawn against
-    (``CIM_TUNER_PEAK_FLOPS``, default TPU v5e bf16)."""
-    return _env_float("CIM_TUNER_PEAK_FLOPS", DEFAULT_PEAK_FLOPS)
-
-
-def peak_bw() -> float:
-    """Peak memory bandwidth in bytes/s (``CIM_TUNER_PEAK_BW``, default
-    TPU v5e HBM)."""
-    return _env_float("CIM_TUNER_PEAK_BW", DEFAULT_PEAK_BW)
-
-
-def roofline_utilization(flops: float, nbytes: float,
-                         seconds: float) -> float:
+def roofline_utilization(flops: float, nbytes: float, seconds: float,
+                         peaks: DevicePeaks) -> float:
     """Achieved FLOP/s over the roofline-attainable rate for one call.
 
-    Attainable is ``min(peak_flops, peak_bw * intensity)`` with
+    Attainable is ``min(peaks.flops, peaks.bw * intensity)`` with
     ``intensity = flops / nbytes``; zero-byte kernels are compute-bound
     by definition."""
     if seconds <= 0 or flops <= 0:
         return 0.0
     achieved = flops / seconds
     if nbytes > 0:
-        attainable = min(peak_flops(), peak_bw() * (flops / nbytes))
+        attainable = min(peaks.flops, peaks.bw * (flops / nbytes))
     else:
-        attainable = peak_flops()
+        attainable = peaks.flops
     return achieved / attainable if attainable > 0 else 0.0
 
 
@@ -184,8 +199,11 @@ def profiled_call(kernel: str, fn, bucket: str, args: tuple,
         flops, nbytes = cost
         _M_FLOPS.set(flops, kernel=kernel, bucket=bucket)
         _M_BYTES.set(nbytes, kernel=kernel, bucket=bucket)
-        _M_ROOF.set(roofline_utilization(flops, nbytes, sp.duration_s),
-                    kernel=kernel, bucket=bucket)
+        peaks = PEAKS.get(_device_kind())
+        if peaks is not None:
+            _M_ROOF.set(roofline_utilization(flops, nbytes, sp.duration_s,
+                                             peaks),
+                        kernel=kernel, bucket=bucket)
     return out
 
 
@@ -228,11 +246,13 @@ def summary(records: typing.Sequence[MeasurementRecord] | None = None,
             ) -> list[dict]:
     """Per-(kernel, bucket) profile rows, sorted: call count, mean
     microseconds, FLOPs/bytes and roofline utilization (0.0 when cost
-    analysis was unavailable).
+    analysis was unavailable, ``None`` on a device kind with no
+    :data:`PEAKS` entry).
 
     With ``records`` (e.g. the return of :func:`run_microbench`) the rows
     aggregate exactly those measurements; without, they come from the
     process-wide metrics registry (everything profiled so far)."""
+    peaks = PEAKS.get(_device_kind())
     if records is not None:
         acc: dict[tuple[str, str], list[MeasurementRecord]] = {}
         for r in records:
@@ -251,8 +271,8 @@ def summary(records: typing.Sequence[MeasurementRecord] | None = None,
                 "us_per_call": us,
                 "flops": flops,
                 "bytes": nbytes,
-                "roofline_utilization": roofline_utilization(
-                    flops, nbytes, us * 1e-6),
+                "roofline_utilization": None if peaks is None else
+                roofline_utilization(flops, nbytes, us * 1e-6, peaks),
             })
         rows.sort(key=lambda r: (r["kernel"], r["bucket"]))
         return rows
@@ -269,8 +289,8 @@ def summary(records: typing.Sequence[MeasurementRecord] | None = None,
             "us_per_call": s / n,
             "flops": _M_FLOPS.value(kernel=kernel, bucket=bucket),
             "bytes": _M_BYTES.value(kernel=kernel, bucket=bucket),
-            "roofline_utilization": _M_ROOF.value(kernel=kernel,
-                                                  bucket=bucket),
+            "roofline_utilization": None if peaks is None else
+            _M_ROOF.value(kernel=kernel, bucket=bucket),
         })
     rows.sort(key=lambda r: (r["kernel"], r["bucket"]))
     return rows
@@ -309,7 +329,8 @@ def _microbench_cases(kernels: tuple[str, ...], rng) -> list[tuple]:
                           ops.flash_attention, (q, k, v),
                           {"causal": True, "bq": bq, "bk": bk}))
     if "selective_scan" in kernels:
-        bs, t, i, s = 1, 64, 32, 8
+        # channel tiles are lane blocks: multiples of 128 on the TPU
+        bs, t, i, s = 1, 64, 256, 16
         xi = jnp.asarray(rng.standard_normal((bs, t, i)), jnp.float32)
         dt = jnp.asarray(np.abs(rng.standard_normal((bs, t, i))) * 0.1,
                          jnp.float32)
@@ -318,7 +339,7 @@ def _microbench_cases(kernels: tuple[str, ...], rng) -> list[tuple]:
         aa = jnp.asarray(-np.abs(rng.standard_normal((i, s))),
                          jnp.float32)
         h0 = jnp.zeros((bs, i, s), jnp.float32)
-        for ct, ci in ((16, 16), (32, 32)):
+        for ct, ci in ((16, 128), (32, 256)):
             cases.append(("selective_scan", f"ct{ct}xci{ci}",
                           ops.selective_scan, (xi, dt, bm, cm, aa, h0),
                           {"ct": ct, "ci": ci}))

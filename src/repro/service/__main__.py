@@ -248,10 +248,11 @@ def _cmd_profile(args) -> int:
         print(f"{'kernel':<16} {'bucket':<18} {'calls':>6} "
               f"{'us/call':>12} {'flops':>12} {'bytes':>12} {'roofline':>9}")
         for r in rows:
+            roof = r["roofline_utilization"]
             print(f"{r['kernel']:<16} {r['bucket']:<18} "
                   f"{r['calls']:>6} {r['us_per_call']:>12.1f} "
                   f"{r['flops']:>12.3g} {r['bytes']:>12.3g} "
-                  f"{r['roofline_utilization']:>9.2e}")
+                  + (f"{roof:>9.2e}" if roof is not None else f"{'n/a':>9}"))
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
             f.write(obs.registry().render())
@@ -289,11 +290,12 @@ def _cmd_calibrate(args) -> int:
         except ValueError as exc:
             print(f"error: {exc}", file=sys.stderr)
             return 2
+    from repro.obs.profile import UnknownDeviceError
     try:
         report = cal.fit_report(records, holdout_fraction=args.holdout,
                                 seed=args.seed)
         corrections = cal.fit_corrections(records)
-    except ValueError as exc:
+    except (ValueError, UnknownDeviceError) as exc:
         print(f"error: {exc}", file=sys.stderr)
         return 2
     payload = None

@@ -159,6 +159,9 @@ class DSEServer:
         self._httpd.dse = self                         # type: ignore[attr-defined]
         self._thread: threading.Thread | None = None
         self._shut = False
+        #: why the kernel profile warm-up failed (``/healthz`` reports the
+        #: server unhealthy while this is set)
+        self.warmup_error: str | None = None
 
     # ------------------------------------------------------------- #
     # lifecycle
@@ -182,7 +185,8 @@ class DSEServer:
         With ``CIM_TUNER_PROFILE`` set, a background warm-up runs the
         kernel micro-profile pass once so ``/v1/metrics`` serves real
         ``cim_kernel_*`` series (with exemplars into this process's
-        ``/v1/trace``) from the first scrape."""
+        ``/v1/trace``) from the first scrape; if it fails, ``/healthz``
+        answers 503."""
         self._thread = threading.Thread(
             target=self._httpd.serve_forever, kwargs={"poll_interval": 0.1},
             name="cim-tuner-dse-http", daemon=True)
@@ -196,9 +200,11 @@ class DSEServer:
     def _profile_warmup(self) -> None:
         try:
             rows = obs.profile.run_microbench()
-            self.log.info("kernel profile warm-up: %d series", len(rows))
-        except Exception as exc:           # noqa: BLE001 -- never fatal
-            self.log.warning("kernel profile warm-up failed: %r", exc)
+        except Exception as exc:   # noqa: BLE001 -- surfaced by /healthz
+            self.warmup_error = f"kernel profile warm-up failed: {exc!r}"
+            self.log.error("%s", self.warmup_error)
+            return
+        self.log.info("kernel profile warm-up: %d series", len(rows))
 
     def shutdown(self, drain: bool = True,
                  timeout: float | None = 30.0) -> None:
@@ -401,11 +407,15 @@ class _Handler(BaseHTTPRequestHandler):
             with obs.span("server.request", histogram=_M_HTTP_S.labels(
                     endpoint=route), endpoint=route, method="GET"):
                 if path == "/healthz":
-                    self._send_json(200, {
-                        "ok": True, "service": "cim-tuner-dse",
+                    error = self.dse.warmup_error
+                    health = {
+                        "ok": error is None, "service": "cim-tuner-dse",
                         "pid": os.getpid(),
                         "uptime_s": round(
-                            time.time() - self.dse._started_s, 3)})
+                            time.time() - self.dse._started_s, 3)}
+                    if error is not None:
+                        health["error"] = error
+                    self._send_json(200 if error is None else 503, health)
                 elif path == "/v1/stats":
                     self._send_json(200, self.dse.stats())
                 elif path == "/v1/metrics":

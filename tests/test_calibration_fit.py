@@ -26,6 +26,9 @@ from repro.core.calibration import (
 )
 from repro.obs import profile
 
+#: the fit's roofline peaks, passed in (the CPU has no published peaks)
+PEAKS = profile.DevicePeaks(197e12, 819e9, "synthetic test peaks")
+
 
 @pytest.fixture(autouse=True)
 def _fresh_calibration(monkeypatch):
@@ -42,7 +45,7 @@ def _synthetic_records(compute: float, memory: float, n: int = 12,
 
     flops:bytes ratios are spread out so the two roofline features are
     far from collinear and the joint 2x2 solve is well conditioned."""
-    pf, pb = profile.peak_flops(), profile.peak_bw()
+    pf, pb = PEAKS.flops, PEAKS.bw
     records = []
     for i in range(n):
         flops = 1e9 * (i + 1)
@@ -59,7 +62,7 @@ def _synthetic_records(compute: float, memory: float, n: int = 12,
 
 
 def test_fit_recovers_known_distortion():
-    cf = fit_corrections(_synthetic_records(compute=3.7, memory=0.4))
+    cf = fit_corrections(_synthetic_records(compute=3.7, memory=0.4), PEAKS)
     assert cf.compute == pytest.approx(3.7, rel=1e-6)
     assert cf.memory == pytest.approx(0.4, rel=1e-6)
     assert cf.update == cf.memory, "update must ride the memory term"
@@ -69,12 +72,12 @@ def test_fit_recovers_known_distortion():
 
 
 def test_fit_survives_noise_and_clamps():
-    cf = fit_corrections(_synthetic_records(2.0, 5.0, noise=0.1))
+    cf = fit_corrections(_synthetic_records(2.0, 5.0, noise=0.1), PEAKS)
     assert cf.compute == pytest.approx(2.0, rel=0.35)
     assert cf.memory == pytest.approx(5.0, rel=0.35)
     assert cf.residual_us > 0.0
     # absurd distortions clamp to the documented [1e-3, 1e3] range
-    big = fit_corrections(_synthetic_records(1e9, 1e9))
+    big = fit_corrections(_synthetic_records(1e9, 1e9), PEAKS)
     assert big.compute <= 1e3 and big.memory <= 1e3
 
 
@@ -82,26 +85,27 @@ def test_fit_raises_without_cost_analysis():
     bad = [{"kernel": "k", "bucket": "b", "tiling": "t", "us": 1.0,
             "flops": None, "bytes": None, "seed": 0}]
     with pytest.raises(ValueError, match="no usable measurement"):
-        fit_corrections(bad)
+        fit_corrections(bad, PEAKS)
 
 
 def test_held_out_error_strictly_below_uncalibrated():
     records = _synthetic_records(4.0, 0.25, n=16, noise=0.05)
-    rep = fit_report(records, holdout_fraction=0.25, seed=3)
+    rep = fit_report(records, holdout_fraction=0.25, seed=3, peaks=PEAKS)
     assert rep["holdout_records"] >= 1
     assert rep["train_records"] + rep["holdout_records"] == len(records)
     assert rep["calibrated_rms_us"] < rep["uncalibrated_rms_us"], \
         "fitted model must beat the identity model on records it never saw"
     assert rep["improvement"] > 1.0
     # the report's factors match a direct fit on the same train split
-    cal = evaluate_corrections(records, fit_corrections(records))
-    assert cal <= evaluate_corrections(records)
+    cal = evaluate_corrections(records, fit_corrections(records, PEAKS),
+                               PEAKS)
+    assert cal <= evaluate_corrections(records, None, PEAKS)
 
 
 def test_version_stable_and_content_addressed():
-    a = fit_corrections(_synthetic_records(3.0, 0.5))
-    b = fit_corrections(_synthetic_records(3.0, 0.5))
-    c = fit_corrections(_synthetic_records(3.1, 0.5))
+    a = fit_corrections(_synthetic_records(3.0, 0.5), PEAKS)
+    b = fit_corrections(_synthetic_records(3.0, 0.5), PEAKS)
+    c = fit_corrections(_synthetic_records(3.1, 0.5), PEAKS)
     assert calibration_version(a) == calibration_version(b)
     assert calibration_version(a) != calibration_version(c)
     assert calibration_version(None) == "uncalibrated"
@@ -110,10 +114,10 @@ def test_version_stable_and_content_addressed():
 
 def test_artifact_round_trip(tmp_path):
     records = _synthetic_records(2.5, 0.8)
-    cf = fit_corrections(records)
+    cf = fit_corrections(records, PEAKS)
     path = str(tmp_path / "calibration.json")
     payload = save_calibration(path, cf, records=records,
-                               report=fit_report(records))
+                               report=fit_report(records, peaks=PEAKS))
     loaded, raw = load_calibration(path)
     assert loaded == cf
     assert raw["version"] == payload["version"] == calibration_version(cf)
@@ -156,12 +160,12 @@ def test_default_cost_model_follows_env_pin(tmp_path, monkeypatch):
     assert not default_cost_model().calibrated
     records = _synthetic_records(3.0, 0.5)
     path = str(tmp_path / "cal.json")
-    save_calibration(path, fit_corrections(records), records=records)
+    save_calibration(path, fit_corrections(records, PEAKS), records=records)
     monkeypatch.setenv(CALIBRATION_ENV, path)
     reset_calibration_state()               # env changed -> re-resolve
     cm = default_cost_model()
     assert cm.calibrated
-    assert cm.version == calibration_version(fit_corrections(records))
+    assert cm.version == calibration_version(fit_corrections(records, PEAKS))
     assert math.isfinite(cm.tech.e_mac_pj)
     monkeypatch.delenv(CALIBRATION_ENV)
     reset_calibration_state()

@@ -132,3 +132,29 @@ def test_infeasible_strategies_get_sentinel():
         ip = _closed_form(macro, cfg, m, k, n, ALL_STRATEGIES[0])  # NR-IP-AF
     assert float(wp.latency_cycles) == INFEASIBLE
     assert float(ip.latency_cycles) < INFEASIBLE
+
+
+@pytest.mark.parametrize("way", ["ceil", "floor"])
+def test_integer_division_exact_under_an_inexact_divide(way):
+    """The cost model's ceil/floor of integer quotients stay exact in
+    float32 when the divide lands one ulp off either way, as the TPU's
+    does (21 / 7 -> 3.0000002), and equal plain ceil/floor where it is
+    correctly rounded."""
+    import jax.numpy as jnp
+
+    from repro.core import cost_model
+
+    a, b = np.meshgrid(np.arange(1, 4097, dtype=np.float32),
+                       np.arange(1, 257, dtype=np.float32))
+    ia, ib = a.astype(np.int64), b.astype(np.int64)
+    exact = -(-ia // ib) if way == "ceil" else ia // ib
+    snap = cost_model._snap_ceil if way == "ceil" else cost_model._snap_floor
+    rnd = np.ceil if way == "ceil" else np.floor
+    q = a / b
+    for off in (np.nextafter(q, np.float32(np.inf)), q,
+                np.nextafter(q, np.float32(-np.inf))):
+        got = np.asarray(snap(jnp.asarray(rnd(off)), a, b))
+        np.testing.assert_array_equal(got.astype(np.int64), exact)
+    plain = cost_model._ceil if way == "ceil" else cost_model._fdiv
+    np.testing.assert_array_equal(
+        np.asarray(plain(jnp.asarray(a), jnp.asarray(b))), rnd(q))

@@ -281,15 +281,15 @@ def test_profile_gate_roofline_and_instrument(monkeypatch):
     assert profile.profiling_enabled()
 
     # roofline: attainable is min(peak compute, bw * intensity)
-    monkeypatch.setenv("CIM_TUNER_PEAK_FLOPS", "100")
-    monkeypatch.setenv("CIM_TUNER_PEAK_BW", "10")
+    peaks = profile.DevicePeaks(flops=100.0, bw=10.0, source="test")
     # intensity 1 flop/byte -> bw-bound at 10 FLOP/s; achieving 5 = 50%
-    assert profile.roofline_utilization(5, 5, 1.0) == pytest.approx(0.5)
+    assert profile.roofline_utilization(5, 5, 1.0, peaks) \
+        == pytest.approx(0.5)
     # huge intensity -> compute-bound at 100 FLOP/s
-    assert profile.roofline_utilization(100, 0.001, 1.0) \
+    assert profile.roofline_utilization(100, 0.001, 1.0, peaks) \
         == pytest.approx(1.0)
-    assert profile.roofline_utilization(0, 0, 1.0) == 0.0
-    assert profile.roofline_utilization(1, 1, 0.0) == 0.0
+    assert profile.roofline_utilization(0, 0, 1.0, peaks) == 0.0
+    assert profile.roofline_utilization(1, 1, 0.0, peaks) == 0.0
 
     calls = []
     wrapped = profile.instrument(
@@ -308,6 +308,23 @@ def test_profile_gate_roofline_and_instrument(monkeypatch):
     rows = [r for r in profile.summary() if r["kernel"] == "t_kernel"]
     assert rows and rows[0]["bucket"] == "b4" \
         and rows[0]["us_per_call"] > 0
+
+
+def test_device_peaks_table_rejects_unknown_kinds():
+    from repro.obs import profile
+
+    v5e = profile.device_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.bw) == (197e12, 819e9) and v5e.source
+    with pytest.raises(profile.UnknownDeviceError, match="cpu"):
+        profile.device_peaks("cpu")
+    # the CPU the tests run on has no entry: no roofline gauge, and a
+    # records summary reports the utilization as unknown
+    with pytest.raises(profile.UnknownDeviceError):
+        profile.device_peaks()
+    rows = profile.summary([{"kernel": "k", "bucket": "b", "tiling": "t",
+                             "us": 1.0, "flops": 1.0, "bytes": 1.0,
+                             "seed": 0}])
+    assert rows[0]["roofline_utilization"] is None
 
 
 # ------------------------------------------------------------------ #

@@ -63,6 +63,27 @@ def _post_json(url: str, payload) -> dict:
 # ------------------------------------------------------------------ #
 # spec round-trip + status endpoints
 # ------------------------------------------------------------------ #
+def test_failed_profile_warmup_makes_healthz_unhealthy(tmp_path,
+                                                       monkeypatch):
+    from repro import obs
+
+    def broken(*a, **kw):
+        raise RuntimeError("kernel refused")
+
+    srv = _server(tmp_path)
+    try:
+        assert _get_json(f"{srv.url}/healthz")["ok"] is True
+        monkeypatch.setattr(obs.profile, "run_microbench", broken)
+        srv._profile_warmup()
+        with pytest.raises(urllib.error.HTTPError) as err:
+            _get_json(f"{srv.url}/healthz")
+        assert err.value.code == 503
+        body = json.loads(err.value.read().decode())
+        assert body["ok"] is False and "kernel refused" in body["error"]
+    finally:
+        srv.shutdown()
+
+
 def test_post_jobs_roundtrip_including_portfolio(tmp_path):
     srv = _server(tmp_path)
     try:

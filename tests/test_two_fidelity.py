@@ -22,9 +22,12 @@ from repro.core.calibration import (
     save_calibration,
 )
 from repro.core.macro import TPDCIM_MACRO
+from repro.obs.profile import DevicePeaks
 from repro.search import FIDELITIES, PortfolioSettings, SASettings
 from repro.service.queue import _normalize_submit_args
 
+#: the fit's roofline peaks, passed in (the CPU has no published peaks)
+PEAKS = DevicePeaks(197e12, 819e9, "synthetic test peaks")
 SMALL = DesignSpace(mr=(1, 2, 3), mc=(1, 2), scr=(1, 4, 16),
                     is_kb=(2, 16, 128), os_kb=(2, 16, 64))
 
@@ -36,8 +39,7 @@ def _job(objective="ee"):
 
 
 def _synthetic_records(n: int = 8) -> list[dict]:
-    from repro.obs import profile
-    pf, pb = profile.peak_flops(), profile.peak_bw()
+    pf, pb = PEAKS.flops, PEAKS.bw
     return [{"kernel": "cim_matmul", "bucket": f"b{i}", "tiling": "AF",
              "us": 2.0 * (1e9 * (i + 1)) / pf * 1e6
              + 0.5 * (1e6 * (n - i)) / pb * 1e6,
@@ -51,7 +53,8 @@ def pinned_artifact(tmp_path, monkeypatch):
     measured rung never runs a live kernel sweep inside the test."""
     records = _synthetic_records()
     path = str(tmp_path / "calibration.json")
-    save_calibration(path, fit_corrections(records), records=records)
+    save_calibration(path, fit_corrections(records, PEAKS),
+                     records=records)
     monkeypatch.setenv(CALIBRATION_ENV, path)
     reset_calibration_state()
     yield path
