@@ -108,7 +108,20 @@ _M_RUN_S = _REG.histogram(
     "cim_engine_run_seconds", "Wall-clock of ExplorationEngine.run calls")
 _M_COMPILE_S = _REG.histogram(
     "cim_engine_compile_seconds",
-    "First-call (trace + XLA compile) latency per cached executable")
+    "Executable calls that re-traced: trace + XLA compile (or a load "
+    "from the persistent compile cache) latency")
+_M_TRACES = _REG.counter(
+    "cim_engine_traces_total",
+    "Traces of each cost-evaluation executable's Python body (one per "
+    "new jobs-per-dispatch count or shape)", ("executable",))
+_M_TRACES.inc(0, executable="one_job_sweep")  # eager: present when idle
+_M_PHASE_S = _REG.histogram(
+    "cim_engine_phase_seconds",
+    "Wall-clock of the engine's phases (prepare, bucket, prune, "
+    "executable, finish)", ("phase",))
+#: one histogram child per phase; the phases never nest in one another
+_PHASES = {ph: _M_PHASE_S.labels(phase=ph) for ph in
+           ("prepare", "bucket", "prune", "executable", "finish")}
 _M_PULLS = _REG.counter(
     "cim_search_pulls_total",
     "Portfolio pulls granted per backend by the budget allocator",
@@ -375,6 +388,53 @@ class _PreparedJob(typing.NamedTuple):
     ops_pad: int                     # operator bucket width
     mat: np.ndarray                  # [5, L] axis-value matrix (unpadded L)
     lens: np.ndarray                 # [5]
+    key: str | None = None           # canonical job key (span ``job`` arg)
+
+
+def _phase(name: str, **args):
+    """A span of one engine phase, observed in ``cim_engine_phase_seconds``
+    under ``phase=name``."""
+    return obs.span(f"engine.{name}", histogram=_PHASES[name], **args)
+
+
+def _fallback_arg(fallback: bool) -> dict:
+    """The span arg that marks a phase run by a snap fallback."""
+    return {"fallback": True} if fallback else {}
+
+
+#: traces made on this thread so far; an executable call that raised it
+#: re-traced (the counter is per thread, so concurrent engines never
+#: claim one another's traces)
+_THREAD_TRACES = threading.local()
+
+
+def _note_trace(executable: str) -> None:
+    """Called from an executable's traced Python body, which runs once
+    per trace (a new shape such as a new jobs-per-dispatch count, and
+    again when the persistent compile cache then serves the compile)."""
+    _M_TRACES.inc(executable=executable)
+    _THREAD_TRACES.n = getattr(_THREAD_TRACES, "n", 0) + 1
+
+
+def _count_traces(fn, executable: str):
+    """Wrap a jitted executable so each call that re-traced is recorded
+    as an ``engine.compile`` span (args: executable, J) and a
+    ``cim_engine_compile_seconds`` observation; other calls pass
+    through."""
+    def wrapper(stacked, *a):
+        before = getattr(_THREAD_TRACES, "n", 0)
+        t0 = time.perf_counter()
+        out = fn(stacked, *a)
+        if getattr(_THREAD_TRACES, "n", 0) != before:
+            dt = time.perf_counter() - t0
+            jobs = int(np.shape(jax.tree.leaves(stacked)[0])[0])
+            obs.record("engine.compile", t0, dt, histogram=_M_COMPILE_S,
+                       executable=executable, J=jobs)
+            _LOG.debug("traced %s at J=%d in %.2fs", executable, jobs, dt)
+        return out
+
+    wrapper.__wrapped__ = fn         # the jitted callable (``.lower``)
+    return wrapper
 
 
 def _pow2_at_least(n: int, floor: int = 4) -> int:
@@ -501,42 +561,19 @@ class ExplorationEngine:
     # ------------------------------------------------------------- #
     # executable cache
     # ------------------------------------------------------------- #
-    @staticmethod
-    def _time_first_call(fn, label: str):
-        """Wrap a fresh ``jax.jit`` executable so its FIRST invocation --
-        where the lazy trace + XLA compile actually happen -- is recorded
-        as an ``engine.compile`` span and a ``cim_engine_compile_seconds``
-        observation; later calls pass straight through."""
-        state = {"first": True}
-        lock = threading.Lock()
-
-        def wrapper(*a, **kw):
-            with lock:
-                first, state["first"] = state["first"], False
-            if first:
-                t0 = time.perf_counter()
-                with obs.span("engine.compile", histogram=_M_COMPILE_S,
-                              executable=label):
-                    out = fn(*a, **kw)
-                _LOG.debug("compiled %s in %.2fs", label,
-                           time.perf_counter() - t0)
-                return out
-            return fn(*a, **kw)
-
-        wrapper.__wrapped__ = fn         # the jitted callable (``.lower``)
-        return wrapper
-
     def _cached(self, key, build):
-        label = str(key[:2])
-        if not self._use_cache:
-            self.stats.bump("executable_cache_misses")
-            return self._time_first_call(build(), label)
-        hit = key in self._executables
+        """The executable cached under ``key``, else ``build()``'s
+        ``(name, jitted)`` wrapped by :func:`_count_traces`."""
+        hit = self._use_cache and key in self._executables
         self.stats.bump("executable_cache_hits" if hit else
                         "executable_cache_misses")
-        if not hit:
-            self._executables[key] = self._time_first_call(build(), label)
-        return self._executables[key]
+        if hit:
+            return self._executables[key]
+        name, fn = build()
+        wrapped = _count_traces(fn, name)
+        if self._use_cache:
+            self._executables[key] = wrapped
+        return wrapped
 
     def _search_executable(self, backend, ops_pad: int, axes_pad: int,
                            settings):
@@ -558,14 +595,20 @@ class ExplorationEngine:
         key = (backend.name, ops_pad, axes_pad, cache_settings,
                bool(jax.config.jax_enable_x64))
 
+        name = f"one_job_{backend.name}"
+
         def build():
             def one_job(job, mat, lens, keys):
+                _note_trace(name)
+
                 def objective(cfg_row):
                     return cost_model.job_objective(
                         job, cfg_row, self.penalty_scale)
                 return backend.run(objective, mat, lens, job.bw, settings,
                                    keys)
-            return jax.jit(jax.vmap(one_job))
+            # the name the device trace's module carries: jit_<name>
+            one_job.__name__ = one_job.__qualname__ = name
+            return name, jax.jit(jax.vmap(one_job))
 
         return self._cached(key, build)
 
@@ -574,12 +617,14 @@ class ExplorationEngine:
                bool(jax.config.jax_enable_x64))
 
         def build():
-            def one_job(job, cand_block):
+            def one_job_sweep(job, cand_block):
+                _note_trace("one_job_sweep")
+
                 def objective(cfg_row):
                     return cost_model.job_objective(
                         job, cfg_row, self.penalty_scale)
                 return jax.vmap(objective)(cand_block)
-            return jax.jit(jax.vmap(one_job))
+            return "one_job_sweep", jax.jit(jax.vmap(one_job_sweep))
 
         return self._cached(key, build)
 
@@ -657,7 +702,15 @@ class ExplorationEngine:
         submission.  Their results are appended AFTER the initial jobs'
         results, in admission order.
         """
-        t_start = time.perf_counter()
+        with obs.span("engine.run", histogram=_M_RUN_S,
+                      jobs=len(jobs)) as sp:
+            return self._run(jobs, method, settings, sa_settings, keys,
+                             admit, sp)
+
+    def _run(self, jobs, method, settings, sa_settings, keys, admit,
+             sp: obs.Span) -> list[ExploreResult]:
+        """:meth:`run` inside its ``engine.run`` span."""
+        t_start = sp.t0
         if settings is None:
             settings = sa_settings
         methods = [method or j.search_method for j in jobs]
@@ -687,7 +740,10 @@ class ExplorationEngine:
                 first_of[k] = i
                 unique.append(i)
 
-        prepared = {i: self._prepare(jobs[i]) for i in unique}
+        sp.set(unique=len(unique))
+        with _phase("prepare", jobs=len(unique)):
+            prepared = {i: self._prepare(jobs[i])._replace(key=keys[i])
+                        for i in unique}
         self.stats.bump("jobs", len(jobs))
 
         results: list[ExploreResult | None] = [None] * len(jobs)
@@ -696,37 +752,35 @@ class ExplorationEngine:
             [(i, prepared[i]) for i in unique], methods, eff)
         if admit is not None:
             self._check_admittable(bucket_groups)
-        with obs.span("engine.run", histogram=_M_RUN_S,
-                      jobs=len(jobs), unique=len(unique)):
-            for (bucket, group_settings), members in bucket_groups.items():
-                m = bucket[0]
-                idxs = [i for i, _ in members]
-                batch = [p for _, p in members]
-                self.stats.bump("batches")
-                _LOG.debug("batch method=%s jobs=%d bucket=%s",
-                           m, len(idxs), bucket)
-                with obs.span("engine.batch", method=m, jobs=len(idxs),
-                              bucket=str(bucket)):
-                    if m == "exhaustive":
-                        outs = self._run_exhaustive_batch(batch)
+        for (bucket, group_settings), members in bucket_groups.items():
+            m = bucket[0]
+            idxs = [i for i, _ in members]
+            batch = [p for _, p in members]
+            self.stats.bump("batches")
+            _LOG.debug("batch method=%s jobs=%d bucket=%s",
+                       m, len(idxs), bucket)
+            with obs.span("engine.batch", method=m, jobs=len(idxs),
+                          bucket=str(bucket)):
+                if m == "exhaustive":
+                    outs = self._run_exhaustive_batch(batch)
+                else:
+                    backend = get_backend(m)
+                    if backend.composite:
+                        outs = self._run_portfolio_batch(
+                            batch, group_settings,
+                            job_keys=[keys[i] for i in idxs],
+                            admit=None if admit is None else
+                            self._wrap_admit(admit, bucket, m))
+                        # rung-admitted jobs ride behind the initial
+                        # batch; their results resolve positionally
+                        # after every submitted job's
+                        admitted_results = list(outs[len(idxs):])
+                        outs = outs[:len(idxs)]
                     else:
-                        backend = get_backend(m)
-                        if backend.composite:
-                            outs = self._run_portfolio_batch(
-                                batch, group_settings,
-                                job_keys=[keys[i] for i in idxs],
-                                admit=None if admit is None else
-                                self._wrap_admit(admit, bucket, m))
-                            # rung-admitted jobs ride behind the initial
-                            # batch; their results resolve positionally
-                            # after every submitted job's
-                            admitted_results = list(outs[len(idxs):])
-                            outs = outs[:len(idxs)]
-                        else:
-                            outs = self._run_search_batch(batch, backend,
-                                                          group_settings)
-                for i, out in zip(idxs, outs):
-                    results[i] = out
+                        outs = self._run_search_batch(batch, backend,
+                                                      group_settings)
+            for i, out in zip(idxs, outs):
+                results[i] = out
         fanout: dict[str, int] = {}
         for i, k in enumerate(keys):
             if results[i] is None:
@@ -783,7 +837,9 @@ class ExplorationEngine:
         in one batched call (the service queue groups submissions by this
         so each micro-batch dispatches as exactly one ``run()``)."""
         method = method or job.search_method
-        return self._bucket_key(self._prepare(job), method)
+        with _phase("bucket"):
+            p = self._prepare(job)
+        return self._bucket_key(p, method)
 
     @staticmethod
     def _bucket_key(p: _PreparedJob, method: str) -> tuple:
@@ -837,7 +893,8 @@ class ExplorationEngine:
         def engine_admit() -> list:
             out = []
             for job, key in admit():
-                p = self._prepare(job)
+                with _phase("prepare", job=key):
+                    p = self._prepare(job)._replace(key=key)
                 got = self._bucket_key(p, method)
                 if got != bucket:
                     raise ValueError(
@@ -900,10 +957,11 @@ class ExplorationEngine:
         """One batched backend call over a shape bucket.  Returns numpy
         ``(best_idx [J, members, 5], best_val [J, members],
         trace [J, steps])``."""
-        best_idx, best_val, trace = self._dispatch_backend_async(
-            batch, backend, settings)
-        return (np.asarray(best_idx), np.asarray(best_val),
-                np.asarray(trace))
+        with _phase("executable", jobs=len(batch)):
+            best_idx, best_val, trace = self._dispatch_backend_async(
+                batch, backend, settings)
+            return (np.asarray(best_idx), np.asarray(best_val),
+                    np.asarray(trace))
 
     def _wrap_search_winner(
         self, p: _PreparedJob, method: str,
@@ -912,17 +970,12 @@ class ExplorationEngine:
         trace: np.ndarray,             # [steps]
     ) -> ExploreResult:
         """Shared epilogue of every stochastic backend: pick the winning
-        member, snap-verify the area budget, attach diagnostics."""
+        member, snap-verify the area budget, attach diagnostics.  The
+        ``engine.finish`` phase covers all of it but a snap fallback,
+        which runs first, under its own ``engine.fallback`` span."""
         job = p.job
         winner = int(np.argmin(best_val))
         vals = p.mat[np.arange(5), best_idx[winner]]
-        diag = SearchResult(
-            best_cfg=jnp.asarray(
-                np.concatenate([vals, [float(job.bw)]])),
-            best_value=jnp.asarray(best_val[winner]),
-            best_per_chain=jnp.asarray(best_val),
-            trace_best=jnp.asarray(trace),
-        )
         cfg = AcceleratorConfig(
             *[int(round(v)) for v in vals], bw=job.bw)
         search: dict = {"method": method,
@@ -933,9 +986,18 @@ class ExplorationEngine:
         # penalty let the winner out of budget (rare)
         if accelerator_area_mm2(cfg, job.macro, job.tech) > \
                 job.area_budget_mm2 * 1.001:
-            cfg, stats = self._exhaustive_one(p)
+            with obs.span("engine.fallback", job=p.key):
+                cfg, stats = self._exhaustive_one(p)
             search.update(stats)
-        return self._finish(p, cfg, search, diag)
+        with _phase("finish", job=p.key):
+            diag = SearchResult(
+                best_cfg=jnp.asarray(
+                    np.concatenate([vals, [float(job.bw)]])),
+                best_value=jnp.asarray(best_val[winner]),
+                best_per_chain=jnp.asarray(best_val),
+                trace_best=jnp.asarray(trace),
+            )
+            return self._finish(p, cfg, search, diag)
 
     def _run_search_batch(
         self, batch: list[_PreparedJob], backend, settings,
@@ -1491,20 +1553,24 @@ class ExplorationEngine:
         return results
 
     # ---- exhaustive path ------------------------------------------ #
-    def _pruned_candidates(self, p: _PreparedJob) -> tuple[np.ndarray, dict]:
+    def _pruned_candidates(self, p: _PreparedJob, fallback: bool = False,
+                           ) -> tuple[np.ndarray, dict]:
         job = p.job
-        cands, stats = prune_space(
-            p.job.design_space(), job.macro, job.area_budget_mm2, job.bw,
-            job.tech)
-        if len(cands) == 0:
-            raise ValueError("no feasible hardware point under budget")
-        return candidates_with_bw(cands, job.bw), stats
+        with _phase("prune", job=p.key, **_fallback_arg(fallback)):
+            cands, stats = prune_space(
+                p.job.design_space(), job.macro, job.area_budget_mm2,
+                job.bw, job.tech)
+            if len(cands) == 0:
+                raise ValueError("no feasible hardware point under budget")
+            return candidates_with_bw(cands, job.bw), stats
 
     def _sweep_values(
         self, ops_pad: int, stacked: cost_model.JobParams,
-        cand_rows: list[np.ndarray],
+        cand_rows: list[np.ndarray], fallback: bool = False,
     ) -> list[np.ndarray]:
-        """Evaluate per-job candidate lists in shared [J, CHUNK] blocks."""
+        """Evaluate per-job candidate lists in shared [J, CHUNK] blocks;
+        each block's call, until its values are on the host, is one
+        ``engine.executable`` phase."""
         chunk = self.EXHAUSTIVE_CHUNK
         fn = self._exhaustive_executable(ops_pad)
         n_max = max(len(c) for c in cand_rows)
@@ -1521,7 +1587,9 @@ class ExplorationEngine:
                         if len(part) else np.repeat(c[:1], chunk, axis=0)
                 lanes.append(part)
             block = np.stack(lanes, axis=0)                  # [J, chunk, 6]
-            out = np.asarray(fn(stacked, jnp.asarray(block)))
+            with _phase("executable", block=lo // chunk, jobs=len(lanes),
+                        **_fallback_arg(fallback)):
+                out = np.asarray(fn(stacked, jnp.asarray(block)))
             for jx, c in enumerate(cand_rows):
                 take = min(max(len(c) - lo, 0), chunk)
                 if take:
@@ -1536,21 +1604,22 @@ class ExplorationEngine:
         vals = self._sweep_values(batch[0].ops_pad, stacked, list(cands))
         results = []
         for p, c, v, st in zip(batch, cands, vals, prune_stats):
-            best = int(np.argmin(v))
-            cfg = AcceleratorConfig(
-                *[int(x) for x in c[best][:5]], bw=p.job.bw)
-            search = {"method": "exhaustive",
-                      "merged_ops": len(p.workload.ops),
-                      "raw_ops": len(p.job.workload.ops), **st}
-            results.append(self._finish(p, cfg, search, None))
+            with _phase("finish", job=p.key):
+                best = int(np.argmin(v))
+                cfg = AcceleratorConfig(
+                    *[int(x) for x in c[best][:5]], bw=p.job.bw)
+                search = {"method": "exhaustive",
+                          "merged_ops": len(p.workload.ops),
+                          "raw_ops": len(p.job.workload.ops), **st}
+                results.append(self._finish(p, cfg, search, None))
         return results
 
     def _exhaustive_one(self, p: _PreparedJob) -> tuple[AcceleratorConfig,
                                                         dict]:
         """Pruned-space optimum of a single job (SA snap-fallback)."""
-        rows, stats = self._pruned_candidates(p)
+        rows, stats = self._pruned_candidates(p, fallback=True)
         stacked = _stack_jobs([_job_arrays(p)])
-        v = self._sweep_values(p.ops_pad, stacked, [rows])[0]
+        v = self._sweep_values(p.ops_pad, stacked, [rows], fallback=True)[0]
         best = int(np.argmin(v))
         return AcceleratorConfig(
             *[int(x) for x in rows[best][:5]], bw=p.job.bw), stats

@@ -40,7 +40,15 @@ from repro.obs.recorder import (
     regret_curve,
     render_timeline,
 )
-from repro.obs.trace import Span, Tracer, chrome_trace, span, tracer
+from repro.obs.trace import (
+    Span,
+    Tracer,
+    chrome_trace,
+    current_span,
+    record,
+    span,
+    tracer,
+)
 
 __all__ = [
     "Counter",
@@ -55,6 +63,8 @@ __all__ = [
     "Tracer",
     "tracer",
     "span",
+    "record",
+    "current_span",
     "chrome_trace",
     "FlightRecorder",
     "flight_recorder",

@@ -13,6 +13,16 @@ wrapper: ``repro-service trace --export chrome`` writes a
 ``{"traceEvents": [...]}`` file Perfetto / ``chrome://tracing`` loads
 as-is.
 
+Spans nest: each records the span it opened inside (``args["parent"]``,
+the enclosing span on the same thread), and a span that names no ``batch``
+or ``job`` of its own takes the enclosing span's, so every span of one
+queue dispatch (``batch``) or one job (``job``, its canonical key) can be
+joined from submit to resolve.  While JAX is imported, a span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so a JAX profile taken while
+the process runs shows the span on its own host plane, on the device
+trace's clock; JAX is found through ``sys.modules`` only, so importing or
+using this module never imports JAX nor starts a JAX backend.
+
 Environment:
 
 ``CIM_TUNER_TRACE``
@@ -23,15 +33,17 @@ Environment:
 from __future__ import annotations
 
 import collections
-import contextlib
+import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import typing
 
-__all__ = ["Span", "Tracer", "tracer", "span", "chrome_trace"]
+__all__ = ["Span", "Tracer", "tracer", "span", "record", "current_span",
+           "chrome_trace"]
 
 _DEF_CAPACITY = 8192
 
@@ -44,25 +56,75 @@ def _next_span_id() -> str:
     return f"{os.getpid():x}-{next(_SPAN_SEQ):x}"
 
 
+#: args a span takes from the enclosing span when it names none itself:
+#: the queue's dispatch sequence number and the job's canonical key
+INHERITED = ("batch", "job")
+
+#: the innermost open span of this thread (threads start with none)
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_obs_span", default=None)
+
+
+def current_span() -> "Span | None":
+    """The innermost span open on this thread, or ``None``."""
+    return _CURRENT.get()
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` when JAX is already
+    imported, else ``None``; never imports JAX itself."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
-    """One in-flight timed section; attributes land in the event's
-    ``args``.  ``span_id`` is the process-unique id the event carries in
-    ``/v1/trace`` -- histogram exemplars reference it (see
+    """One timed section, used as a context manager; attributes land in
+    the event's ``args``.  ``span_id`` is the process-unique id the event
+    carries in ``/v1/trace`` -- histogram exemplars reference it (see
     ``obs/metrics.py``)."""
 
-    __slots__ = ("name", "cat", "args", "t0", "duration_s", "span_id")
+    __slots__ = ("name", "cat", "args", "t0", "duration_s", "span_id",
+                 "_tracer", "_histogram", "_token", "_ann")
 
-    def __init__(self, name: str, cat: str, args: dict):
+    def __init__(self, name: str, cat: str, args: dict,
+                 tracer: "Tracer | None" = None, histogram=None):
         self.name = name
         self.cat = cat
         self.args = args
         self.t0 = time.perf_counter()
         self.duration_s: float | None = None
         self.span_id = _next_span_id()
+        self._tracer = tracer
+        self._histogram = histogram
+        self._token = self._ann = None
+        parent = _CURRENT.get()
+        if parent is not None:
+            args["parent"] = parent.span_id
+            for k in INHERITED:
+                if k not in args and k in parent.args:
+                    args[k] = parent.args[k]
 
     def set(self, **kw) -> None:
         """Attach extra args discovered mid-span (e.g. result counts)."""
         self.args.update(kw)
+
+    def __enter__(self) -> "Span":
+        self._token = _CURRENT.set(self)
+        self._ann = _annotation(self.name)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.duration_s = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _CURRENT.reset(self._token)
+        self._tracer._finish(self, self._histogram)
 
 
 class Tracer:
@@ -84,9 +146,8 @@ class Tracer:
         # epoch anchor so perf_counter offsets become absolute-ish ts
         self._epoch_us = time.time() * 1e6 - time.perf_counter() * 1e6
 
-    @contextlib.contextmanager
     def span(self, name: str, *, cat: str = "repro",
-             histogram=None, **args) -> typing.Iterator[Span]:
+             histogram=None, **args) -> Span:
         """Time a ``with`` block as one complete trace event.
 
         ``histogram`` is an optional :class:`repro.obs.metrics.Histogram`
@@ -96,18 +157,27 @@ class Tracer:
         its span in ``/v1/trace``).  Extra keyword args become the
         event's ``args`` payload.
         """
-        sp = Span(name, cat, dict(args))
-        try:
-            yield sp
-        finally:
-            sp.duration_s = time.perf_counter() - sp.t0
-            self._record(sp)
-            if histogram is not None:
-                try:
-                    histogram.observe(sp.duration_s,
-                                      exemplar={"span_id": sp.span_id})
-                except TypeError:      # foreign histogram, no exemplars
-                    histogram.observe(sp.duration_s)
+        return Span(name, cat, args, self, histogram)
+
+    def record(self, name: str, t0: float, duration_s: float, *,
+               cat: str = "repro", histogram=None, **args) -> Span:
+        """Record a section that has already ended (``t0`` on the
+        ``time.perf_counter`` clock) as a child of the current span: for
+        sections known to be worth a span only once they are over, such
+        as an executable call that turned out to re-trace."""
+        sp = Span(name, cat, args)
+        sp.t0, sp.duration_s = t0, duration_s
+        self._finish(sp, histogram)
+        return sp
+
+    def _finish(self, sp: Span, histogram) -> None:
+        self._record(sp)
+        if histogram is not None:
+            try:
+                histogram.observe(sp.duration_s,
+                                  exemplar={"span_id": sp.span_id})
+            except TypeError:      # foreign histogram, no exemplars
+                histogram.observe(sp.duration_s)
 
     def _record(self, sp: Span) -> None:
         ev = {
@@ -168,6 +238,13 @@ def tracer() -> Tracer:
     return _TRACER
 
 
-def span(name: str, *, cat: str = "repro", histogram=None, **args):
+def span(name: str, *, cat: str = "repro", histogram=None, **args) -> Span:
     """``tracer().span(...)`` shorthand -- the one-liner subsystems use."""
     return tracer().span(name, cat=cat, histogram=histogram, **args)
+
+
+def record(name: str, t0: float, duration_s: float, *, cat: str = "repro",
+           histogram=None, **args) -> Span:
+    """``tracer().record(...)`` shorthand."""
+    return tracer().record(name, t0, duration_s, cat=cat,
+                           histogram=histogram, **args)

@@ -28,6 +28,7 @@ arrivals the dispatch is bit-identical to the plain window path
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import inspect
@@ -79,6 +80,12 @@ _M_DEPTH = _REG.gauge(
 _M_WAIT_S = _REG.histogram(
     "cim_queue_wait_seconds",
     "Submit-to-dispatch latency per queue entry")
+_M_PHASE_S = _REG.histogram(
+    "cim_queue_phase_seconds",
+    "Wall-clock of the queue's phases (submit on the client's thread, "
+    "resolve on the worker's)", ("phase",))
+#: one histogram child per phase (eager: the family renders when idle)
+_PHASES = {ph: _M_PHASE_S.labels(phase=ph) for ph in ("submit", "resolve")}
 # continuous-batching scheduler families (docs/scheduler.md); the engine
 # owns the budget-flow counters, the queue owns the admission ones
 _M_SCHED_ADMISSIONS = _REG.counter(
@@ -210,6 +217,16 @@ def _normalize_submit_args(job: ExploreJob, method=None, settings=None,
     return method, settings, job_key(job, method, settings)
 
 
+def _submit_span(jobs: int):
+    """The ``queue.submit`` span of one ``submit``/``submit_many``/
+    ``submit_values`` call; a ``submit`` that ``submit_many`` makes opens
+    none of its own, so each call is observed once."""
+    cur = obs.current_span()
+    if cur is not None and cur.name == "queue.submit":
+        return contextlib.nullcontext()
+    return obs.span("queue.submit", histogram=_PHASES["submit"], jobs=jobs)
+
+
 def _tag_job_exc(exc: BaseException, key: str) -> BaseException:
     """Per-future copy of a dispatch failure, carrying the originating
     ``job_key`` both in the message and as a ``.job_key`` attribute (one
@@ -272,6 +289,9 @@ class JobQueue:
         })
         self._running_group: list[_Entry] | None = None
         self._engine_admits: bool | None = None   # lazy capability probe
+        #: dispatch sequence number: the ``batch`` arg of the spans of
+        #: one engine call, from the engine's down to the store writes
+        self._dispatch_seq = 0
 
     # ------------------------------------------------------------- #
     # engine access (lazy so tests can build queues without JAX work)
@@ -305,28 +325,30 @@ class JobQueue:
         ``fidelity`` ("analytic" | "measured" | shorthand "two")
         overrides the settings' fidelity for fidelity-capable backends
         (the portfolio racer)."""
-        # resolve the effective settings WITHOUT instantiating the default
-        # engine (store-only submissions skip engine construction and its
-        # persistent-cache setup); a default-constructed engine uses
-        # SASettings() too, so the canonical key matches either way
-        method, settings, key = _normalize_submit_args(
-            job, method, settings, sa_settings, fidelity,
-            engine=self._engine)
-        future = ExploreFuture(job, method, key, meta=meta)
-        # submissions arrive from concurrent threads (the HTTP front
-        # door); StatCounters locks each bump so increments never race
-        self.stats.bump("submitted")
+        with _submit_span(1):
+            # resolve the effective settings WITHOUT instantiating the
+            # default engine (store-only submissions skip engine
+            # construction and its persistent-cache setup); a
+            # default-constructed engine uses SASettings() too, so the
+            # canonical key matches either way
+            method, settings, key = _normalize_submit_args(
+                job, method, settings, sa_settings, fidelity,
+                engine=self._engine)
+            future = ExploreFuture(job, method, key, meta=meta)
+            # submissions arrive from concurrent threads (the HTTP front
+            # door); StatCounters locks each bump so increments never race
+            self.stats.bump("submitted")
 
-        if self.store is not None:
-            cached = self.store.get(key)
-            if cached is not None:
-                self.stats.bump("store_hits")
-                future._finish(cached, source="store")
-                return future
+            if self.store is not None:
+                cached = self.store.get(key)
+                if cached is not None:
+                    self.stats.bump("store_hits")
+                    future._finish(cached, source="store")
+                    return future
 
-        self._enqueue("explore", key, job, method, settings, None,
-                      priority, future)
-        return future
+            self._enqueue("explore", key, job, method, settings, None,
+                          priority, future)
+            return future
 
     def submit_many(
         self,
@@ -342,9 +364,10 @@ class JobQueue:
         if len(metas) != len(jobs):
             raise ValueError(
                 f"metas length {len(metas)} != jobs length {len(jobs)}")
-        return [self.submit(j, method, sa_settings, priority, meta=m,
-                            settings=settings, fidelity=fidelity)
-                for j, m in zip(jobs, metas)]
+        with _submit_span(len(jobs)):
+            return [self.submit(j, method, sa_settings, priority, meta=m,
+                                settings=settings, fidelity=fidelity)
+                    for j, m in zip(jobs, metas)]
 
     def submit_values(
         self,
@@ -355,13 +378,14 @@ class JobQueue:
     ) -> ExploreFuture:
         """Admit an explicit candidate sweep (the Pareto path); the future
         resolves to the ``[C]`` objective-value array."""
-        rows = np.asarray(candidates, dtype=np.float64)
-        key = values_key(job, rows)
-        future = ExploreFuture(job, "values", key, meta=meta)
-        self.stats.bump("submitted")
-        self._enqueue("values", key, job, "values", None, rows,
-                      priority, future)
-        return future
+        with _submit_span(1):
+            rows = np.asarray(candidates, dtype=np.float64)
+            key = values_key(job, rows)
+            future = ExploreFuture(job, "values", key, meta=meta)
+            self.stats.bump("submitted")
+            self._enqueue("values", key, job, "values", None, rows,
+                          priority, future)
+            return future
 
     def run_sync(
         self,
@@ -604,45 +628,60 @@ class JobQueue:
 
     def _dispatch(self, batch: list[_Entry]) -> None:
         for group in self._groups(batch):
-            self.stats.bump("dispatches")
-            now = time.perf_counter()
-            for e in group:
-                _M_WAIT_S.observe(now - e.t_submit)
-            _LOG.debug("dispatch %d job(s) kind=%s method=%s wait=%.3fs",
-                       len(group), group[0].kind, group[0].method,
-                       now - min(e.t_submit for e in group))
+            self._dispatch_seq += 1
+            with obs.span("queue.dispatch", batch=self._dispatch_seq,
+                          jobs=len(group), method=group[0].method):
+                self._dispatch_group(group)
+
+    def _dispatch_group(self, group: list[_Entry]) -> None:
+        """One engine call for one executable-signature group, then the
+        resolution of its entries."""
+        self.stats.bump("dispatches")
+        now = time.perf_counter()
+        for e in group:
+            _M_WAIT_S.observe(now - e.t_submit)
+        _LOG.debug("dispatch %d job(s) kind=%s method=%s wait=%.3fs",
+                   len(group), group[0].kind, group[0].method,
+                   now - min(e.t_submit for e in group))
+        with self._lock:
+            self._running_group = group
+        _M_SCHED_GROUPS.set(1)
+        _M_SCHED_GROUP_JOBS.set(len(group))
+        try:
+            if group[0].kind == "values":
+                outs = self.engine.candidate_values(
+                    [e.job for e in group], [e.payload for e in group])
+            else:
+                # pass the canonical keys computed at submit time so the
+                # engine's dedup pass skips re-hashing; the admission
+                # hook (None for non-admittable groups) lets compatible
+                # late arrivals join mid-race, and the engine returns
+                # their results appended behind the dispatched entries'
+                # -- group grows in lockstep
+                admit = self._admission_hook(group)
+                kwargs = {} if admit is None else {"admit": admit}
+                outs = self.engine.run(
+                    [e.job for e in group], method=group[0].method,
+                    settings=group[0].settings,
+                    keys=[e.key for e in group], **kwargs)
+        except Exception as exc:                  # noqa: BLE001 -- reject group
+            self._resolve_group(group, None, exc)
+            return
+        finally:
             with self._lock:
-                self._running_group = group
-            _M_SCHED_GROUPS.set(1)
-            _M_SCHED_GROUP_JOBS.set(len(group))
-            try:
-                if group[0].kind == "values":
-                    outs = self.engine.candidate_values(
-                        [e.job for e in group], [e.payload for e in group])
-                else:
-                    # pass the canonical keys computed at submit time so
-                    # the engine's dedup pass skips re-hashing; the
-                    # admission hook (None for non-admittable groups)
-                    # lets compatible late arrivals join mid-race, and
-                    # the engine returns their results appended behind
-                    # the dispatched entries' -- group grows in lockstep
-                    admit = self._admission_hook(group)
-                    kwargs = {} if admit is None else {"admit": admit}
-                    outs = self.engine.run(
-                        [e.job for e in group], method=group[0].method,
-                        settings=group[0].settings,
-                        keys=[e.key for e in group], **kwargs)
-            except Exception as exc:              # noqa: BLE001 -- reject group
-                self._resolve_group(group, None, exc)
-                continue
-            finally:
-                with self._lock:
-                    self._running_group = None
-                _M_SCHED_GROUPS.set(0)
-                _M_SCHED_GROUP_JOBS.set(0)
-            self._resolve_group(group, outs, None)
+                self._running_group = None
+            _M_SCHED_GROUPS.set(0)
+            _M_SCHED_GROUP_JOBS.set(0)
+        self._resolve_group(group, outs, None)
 
     def _resolve_group(self, group, outs, exc) -> None:
+        with obs.span("queue.resolve", histogram=_PHASES["resolve"],
+                      jobs=len(group)):
+            self._resolve_entries(group, outs, exc)
+
+    def _resolve_entries(self, group, outs, exc) -> None:
+        """Persist each entry's result, drop it from the in-flight map
+        and finish its futures (or fail them all with ``exc``)."""
         for i, e in enumerate(group):
             out = outs[i] if exc is None else None
             if exc is None and e.kind == "explore" and \

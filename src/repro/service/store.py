@@ -287,6 +287,10 @@ class ResultStore:
         ``os.replace``; write failures degrade to a no-op so read-only
         filesystems never break exploration), then enforce the size cap.
         """
+        with obs.span("store.put", tier="local", job=key):
+            self._put(key, result)
+
+    def _put(self, key: str, result: ExploreResult) -> None:
         rec = {"schema": STORE_SCHEMA, "key": key,
                "created_s": time.time(),
                "result": serialize_result(result)}

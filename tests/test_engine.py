@@ -125,3 +125,132 @@ def test_candidate_values_match_objective():
     best = engine.run([job], method="exhaustive")[0]
     np_best = rows[int(np.argmin(vals))]
     assert tuple(int(x) for x in np_best[:5]) == best.config.as_tuple()
+
+
+# ------------------------------------------------------------------ #
+# phase spans, ancestry, retrace counter
+# ------------------------------------------------------------------ #
+_RUN_PHASES = ("engine.prepare", "engine.prune", "engine.executable",
+               "engine.finish")
+
+
+def _phase_sums() -> dict:
+    from repro import obs
+    snap = obs.registry().snapshot()
+    return {k: v for k, v in snap.items()
+            if k.startswith(("cim_engine_phase_seconds_sum",
+                             "cim_engine_run_seconds_sum"))}
+
+
+def _descendants(events: list, root_id: str) -> list:
+    """Events whose parent chain reaches ``root_id``."""
+    by_id = {e["id"]: e for e in events}
+    out = []
+    for e in events:
+        p = e["args"].get("parent")
+        while p is not None and p != root_id:
+            p = by_id[p]["args"].get("parent") if p in by_id else None
+        if p == root_id:
+            out.append(e)
+    return out
+
+
+@pytest.mark.parametrize("method", ["exhaustive", "sa"])
+def test_phase_spans_tile_one_run_without_overlap(method):
+    from repro import obs
+    from repro.core import job_key
+    jobs = [ExploreJob(TPDCIM_MACRO, bert_large_workload(), b,
+                       objective="ee", space=SMALL) for b in (2.0, 2.5)]
+    settings = SASettings(n_chains=8, n_steps=20, seed=1)
+    engine = ExplorationEngine()
+    keys = [job_key(j, method, None if method == "exhaustive" else settings)
+            for j in jobs]
+    engine.run(jobs, method=method, settings=settings
+               if method == "sa" else None)          # compile outside
+    before = _phase_sums()
+    with obs.span("test.dispatch", batch=7):
+        engine.run(jobs, method=method, keys=keys,
+                   settings=settings if method == "sa" else None)
+    after = _phase_sums()
+    events = obs.tracer().events()
+    run = [e for e in events if e["name"] == "engine.run"][-1]
+    assert run["args"]["batch"] == 7 and "parent" in run["args"]
+    inside = _descendants(events, run["id"])
+    phases = sorted((e for e in inside if e["name"] in _RUN_PHASES),
+                    key=lambda e: e["ts"])
+    names = {e["name"] for e in phases}
+    assert {"engine.prepare", "engine.executable", "engine.finish"} <= names
+    assert ("engine.prune" in names) == (method == "exhaustive")
+    for a, b in zip(phases, phases[1:]):          # never nest or overlap
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a, b)
+    for e in inside:
+        assert e["args"]["batch"] == 7
+    per_job = [e for e in phases if e["name"] in ("engine.prune",
+                                                  "engine.finish")]
+    assert sorted(e["args"]["job"] for e in per_job) == sorted(
+        keys * (2 if method == "exhaustive" else 1))
+    grew = {k: after[k] - before.get(k, 0.0) for k in after}
+    run_s = grew.pop("cim_engine_run_seconds_sum")
+    assert 0 < sum(grew.values()) <= run_s
+    assert run_s * 1e6 <= run["dur"] + 1.0
+
+
+def test_retrace_counter_sees_each_new_job_count():
+    from repro import obs
+    from repro.core.engine import _M_TRACES
+    jobs = [ExploreJob(TPDCIM_MACRO, bert_large_workload(), 2.0 + 0.1 * i,
+                       space=SMALL) for i in range(3)]
+    rows = np.array([[1, 1, 1, 2, 2, 256], [2, 1, 4, 16, 16, 256]], float)
+    engine = ExplorationEngine()
+    counter = _M_TRACES.labels(executable="one_job_sweep")
+
+    def sweep(n):
+        n0 = counter.value
+        with obs.span("test.sweep") as sp:
+            engine.candidate_values(jobs[:n], [rows] * n)
+        spans = [e for e in _descendants(obs.tracer().events(), sp.span_id)
+                 if e["name"] == "engine.compile"]
+        return counter.value - n0, spans
+
+    traced2, spans2 = sweep(2)
+    traced3, spans3 = sweep(3)
+    assert traced2 + traced3 == 2
+    assert [s["args"]["J"] for s in spans2 + spans3] == [2, 3]
+    assert {s["args"]["executable"] for s in spans2 + spans3} == {
+        "one_job_sweep"}
+    assert all(s["args"]["parent"] for s in spans2 + spans3)
+    assert sweep(2) == (0, [])
+
+
+def test_executables_are_named_by_what_they_run():
+    engine = ExplorationEngine()
+    from repro.search.base import get_backend
+    sweep = engine._exhaustive_executable(8).__wrapped__
+    sa = engine._search_executable(get_backend("sa"), 8, 8,
+                                   SASettings(n_chains=4, n_steps=4))
+    assert sweep.__name__ == "one_job_sweep"
+    assert sa.__wrapped__.__name__ == "one_job_sa"
+
+
+def test_snap_fallback_is_its_own_span_outside_finish():
+    from repro import obs
+    engine = ExplorationEngine()
+    job = ExploreJob(TPDCIM_MACRO, bert_large_workload(), 2.0, space=SMALL)
+    p = engine._prepare(job)._replace(key="k-fallback")
+    largest = (np.asarray(p.lens) - 1)[None, :]   # over any small budget
+    with obs.span("test.winner") as sp:
+        out = engine._wrap_search_winner(p, "sa", largest,
+                                         np.array([1.0]), np.zeros(3))
+    assert "kept" in out.search, "the winner must have fallen back"
+    inside = _descendants(obs.tracer().events(), sp.span_id)
+    (fb,) = [e for e in inside if e["name"] == "engine.fallback"]
+    (fin,) = [e for e in inside if e["name"] == "engine.finish"]
+    kids = [e for e in inside if e["args"]["parent"] == fb["id"]]
+    assert {e["name"] for e in kids} >= {"engine.prune", "engine.executable"}
+    for e in kids + [fb, fin]:
+        assert e["args"]["job"] == "k-fallback"
+    assert all(e["args"]["fallback"] is True for e in kids
+               if e["name"] != "engine.compile")
+    assert fin["args"]["parent"] == sp.span_id and "fallback" not in \
+        fin["args"]
+    assert fb["ts"] + fb["dur"] <= fin["ts"] + 1e-3

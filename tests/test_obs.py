@@ -390,6 +390,109 @@ def test_tracer_records_spans_and_exports_chrome_shape(tmp_path):
     assert tr.events() == []
 
 
+def test_spans_carry_parent_and_inherit_batch_and_job():
+    tr = Tracer(capacity=16)
+    with tr.span("unit.dispatch", batch=4) as outer:
+        with tr.span("unit.job", job="k1") as mid:
+            with tr.span("unit.leaf"):
+                pass
+            tr.record("unit.after", mid.t0, 0.0)
+        with tr.span("unit.other", job="k2"):
+            pass
+    with tr.span("unit.root"):
+        pass
+    ev = {e["name"]: e for e in tr.events()}
+    assert "parent" not in ev["unit.dispatch"]["args"]
+    assert "parent" not in ev["unit.root"]["args"]
+    assert ev["unit.job"]["args"] == {"job": "k1", "parent": outer.span_id,
+                                      "batch": 4}
+    assert ev["unit.leaf"]["args"] == {"parent": mid.span_id, "batch": 4,
+                                       "job": "k1"}
+    assert ev["unit.after"]["args"]["parent"] == mid.span_id
+    assert ev["unit.other"]["args"]["job"] == "k2"
+    assert obs.current_span() is None
+
+
+def test_spans_nest_per_thread():
+    tr = Tracer(capacity=16)
+    seen = {}
+
+    def worker():
+        with tr.span("unit.thread") as sp:
+            seen["args"] = dict(sp.args)
+
+    with tr.span("unit.main", batch=1):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+    assert seen["args"] == {}, "a thread starts with no enclosing span"
+
+
+def test_span_lands_in_a_jax_profile_on_the_trace_clock(tmp_path):
+    """While a JAX profile runs, a span also shows in its ``/host:CPU``
+    plane; its ring-buffer ``ts``, mapped through one annotation whose
+    wall time is read while it is open, starts where the plane's does."""
+    import glob
+    import time
+
+    import jax
+    tr = Tracer(capacity=8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("unit.clock"):
+            sync_ns = time.time_ns()
+        with tr.span("unit.profiled"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    host = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("unit."):
+                        host[e.name] = (e.start_ns, e.duration_ns)
+    assert set(host) == {"unit.clock", "unit.profiled"}
+    offset_ns = sync_ns - host["unit.clock"][0]
+    (ev,) = tr.events()
+    mapped_ns = ev["ts"] * 1e3 - offset_ns
+    assert abs(mapped_ns - host["unit.profiled"][0]) < 100e3
+    assert host["unit.profiled"][1] >= 2e6
+
+
+def test_queue_spans_join_one_dispatch_from_submit_to_store(tmp_path):
+    from repro.service import JobQueue, ResultStore
+
+    obs.tracer().clear()
+    jobs = [_job(budget=b) for b in (2.0, 2.1, 2.2)]
+    before = obs.registry().snapshot()
+    with JobQueue(engine=CountingStubEngine(),
+                  store=ResultStore(str(tmp_path))) as q:
+        futures = q.submit_many(jobs)
+        for f in futures:
+            f.result(timeout=30)
+    after = obs.registry().snapshot()
+    events = obs.tracer().events()
+    submits = [e for e in events if e["name"] == "queue.submit"]
+    assert [e["args"]["jobs"] for e in submits] == [3], \
+        "submit_many is one submit phase, not one per job"
+    (dispatch,) = [e for e in events if e["name"] == "queue.dispatch"]
+    seq = dispatch["args"]["batch"]
+    (resolve,) = [e for e in events if e["name"] == "queue.resolve"]
+    assert resolve["args"]["parent"] == dispatch["id"]
+    assert resolve["args"]["batch"] == seq
+    puts = [e for e in events if e["name"] == "store.put"]
+    assert sorted(e["args"]["job"] for e in puts) == sorted(
+        f.key for f in futures)
+    assert all(e["args"]["parent"] == resolve["id"] and
+               e["args"]["batch"] == seq for e in puts)
+    for phase, n in (("submit", 1), ("resolve", 1)):
+        key = f'cim_queue_phase_seconds_count{{phase="{phase}"}}'
+        assert after[key] - before.get(key, 0.0) == n
+
+
 def test_tracer_ring_buffer_caps_and_histogram_observes():
     reg = Registry()
     h = reg.histogram("t_span_seconds", "span time", buckets=(60.0,))
