@@ -465,8 +465,10 @@ def workload_cost_core(
     return total_lat, total_en, idx
 
 
-def strategy_mask(strategy_set: str):
-    return jnp.array(
+def strategy_mask(strategy_set: str) -> np.ndarray:
+    """[8] 0/1 mask of the strategies ``strategy_set`` admits; a numpy
+    constant, so building a job's arrays moves nothing to the device."""
+    return np.array(
         [1.0 if s in STRATEGY_SETS[strategy_set] else 0.0
          for s in ALL_STRATEGIES]
     )
@@ -563,6 +565,40 @@ def make_objective_fn(
     return fn
 
 
+def workload_metrics_core(job: JobParams, cfg_row):
+    """Traced workload totals of one job on one config: ``(total latency
+    cycles, total energy pJ, per-op strategy index [P], area mm^2, true
+    ops)``.  Padded operator rows (count 0) add nothing to any total; the
+    engine jits this per operator bucket as its result epilogue."""
+    lat, en, idx = workload_cost_core(
+        job.ops, cfg_row, _STRAT_BITS, job.allowed, job.macro, job.tech,
+        job.obj_code)
+    ops = job.ops
+    true_ops = 2.0 * jnp.sum(ops[:, 0] * ops[:, 1] * ops[:, 2] * ops[:, 3])
+    return lat, en, idx, area_mm2_jnp(cfg_row, job.macro, job.tech), true_ops
+
+
+def metrics_dict(total_lat, total_en, strategy_idx, area_mm2, true_ops,
+                 freq_mhz, n_ops: int) -> dict:
+    """The human-facing metrics of :func:`workload_metrics_core`'s outputs,
+    as Python numbers; ``strategy_idx`` is cut to the ``n_ops`` real
+    operators."""
+    lat, en, ops = (np.float64(x) for x in (total_lat, total_en, true_ops))
+    lat_s = lat / (float(freq_mhz) * 1e6)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        tops_w = ops / (en * 1e-12) / 1e12
+        gops = ops / lat_s / 1e9
+    return {
+        "latency_cycles": float(lat),
+        "latency_s": float(lat_s),
+        "energy_pj": float(en),
+        "tops_w": float(tops_w),
+        "gops": float(gops),
+        "area_mm2": float(area_mm2),
+        "strategy_idx": [int(i) for i in np.asarray(strategy_idx)[:n_ops]],
+    }
+
+
 def workload_metrics(
     workload_ops_arr,
     cfg_row,
@@ -571,22 +607,14 @@ def workload_metrics(
     objective="ee",
     strategy_set: str = "st",
 ) -> dict:
-    """Human-facing PPA metrics for a config (TOPS/W, GOPS, mm^2, ...)."""
-    lat, en, idx = workload_cost(
-        workload_ops_arr, cfg_row, macro, tech, objective, strategy_set
-    )
+    """Human-facing PPA metrics for a config (TOPS/W, GOPS, mm^2, ...),
+    evaluated eagerly through :func:`workload_metrics_core`."""
+    mp, tp = _as_params(macro, tech)
     ops_arr = jnp.asarray(workload_ops_arr)
-    true_ops = 2.0 * jnp.sum(
-        ops_arr[:, 0] * ops_arr[:, 1] * ops_arr[:, 2] * ops_arr[:, 3]
-    )
-    lat_s = lat / (macro.freq_mhz * 1e6)
-    energy_j = en * 1e-12
-    return {
-        "latency_cycles": float(lat),
-        "latency_s": float(lat_s),
-        "energy_pj": float(en),
-        "tops_w": float(true_ops / energy_j / 1e12),
-        "gops": float(true_ops / lat_s / 1e9),
-        "area_mm2": float(area_mm2_jnp(jnp.asarray(cfg_row), macro, tech)),
-        "strategy_idx": [int(i) for i in idx],
-    }
+    cfg_row = jnp.asarray(cfg_row)
+    job = JobParams(
+        ops=ops_arr, macro=mp, tech=tp, allowed=strategy_mask(strategy_set),
+        obj_code=objective_code(objective), area_budget=float("inf"),
+        bw=cfg_row[5])
+    return metrics_dict(*workload_metrics_core(job, cfg_row),
+                        freq_mhz=mp.freq_mhz, n_ops=len(ops_arr))

@@ -112,8 +112,8 @@ _M_COMPILE_S = _REG.histogram(
     "from the persistent compile cache) latency")
 _M_TRACES = _REG.counter(
     "cim_engine_traces_total",
-    "Traces of each cost-evaluation executable's Python body (one per "
-    "new jobs-per-dispatch count or shape)", ("executable",))
+    "Traces of each engine executable's Python body (one per new "
+    "jobs-per-dispatch count or shape)", ("executable",))
 _M_TRACES.inc(0, executable="one_job_sweep")  # eager: present when idle
 _M_PHASE_S = _REG.histogram(
     "cim_engine_phase_seconds",
@@ -451,8 +451,7 @@ def _job_arrays(p: _PreparedJob) -> cost_model.JobParams:
             for v in cost_model.macro_params(j.macro, j.tech)]),
         tech=cost_model.TechParams(*[
             np.float64(v) for v in cost_model.tech_params(j.tech)]),
-        allowed=np.asarray(cost_model.strategy_mask(j.strategy_set),
-                           dtype=np.float64),
+        allowed=cost_model.strategy_mask(j.strategy_set),
         obj_code=np.int32(cost_model.objective_code(j.objective)),
         area_budget=np.float64(j.area_budget_mm2),
         bw=np.float64(j.bw),
@@ -461,6 +460,22 @@ def _job_arrays(p: _PreparedJob) -> cost_model.JobParams:
 
 def _stack_jobs(rows: list[cost_model.JobParams]) -> cost_model.JobParams:
     return jax.tree.map(lambda *xs: np.stack(xs), *rows)
+
+
+#: widths of a :func:`_finish_row` after its operators: macro and tech
+#: constants, strategy mask, objective code, config row
+_FINISH_TAIL = (len(cost_model.MacroParams._fields),
+                len(cost_model.TechParams._fields), len(ALL_STRATEGIES), 1, 6)
+
+
+def _finish_row(p: _PreparedJob, cfg: AcceleratorConfig) -> np.ndarray:
+    """One job's epilogue inputs as one ``[1, 5 * ops_pad + 38]`` float64
+    row (operators, then :data:`_FINISH_TAIL`), so the call transfers one
+    array to the device and not one per leaf."""
+    j = _job_arrays(p)
+    return np.concatenate([
+        j.ops.ravel(), j.macro, j.tech, j.allowed, [j.obj_code],
+        [cfg.mr, cfg.mc, cfg.scr, cfg.is_kb, cfg.os_kb, cfg.bw]])[None]
 
 
 def _spearman(a: np.ndarray, b: np.ndarray) -> float:
@@ -625,6 +640,36 @@ class ExplorationEngine:
                         job, cfg_row, self.penalty_scale)
                 return jax.vmap(objective)(cand_block)
             return "one_job_sweep", jax.jit(jax.vmap(one_job_sweep))
+
+        return self._cached(key, build)
+
+    def _finish_executable(self, ops_pad: int):
+        """The result epilogue of one operator bucket width: the delivered
+        config's totals, area and per-op strategies of one job
+        (:func:`cost_model.workload_metrics_core`), read from one
+        :func:`_finish_row` and returned as one vector (``[lat, en, area,
+        true_ops, strategy_idx...]``), so a call moves one array each way.
+        Vmapped over a one-job stack, the compile depends on ``ops_pad``
+        alone."""
+        key = ("finish", ops_pad, bool(jax.config.jax_enable_x64))
+        cuts = np.cumsum(_FINISH_TAIL)[:-1]
+
+        def build():
+            def finish_metrics(row):
+                _note_trace("finish_metrics")
+                ops = row[:5 * ops_pad].reshape(ops_pad, 5)
+                macro, tech, allowed, code, cfg_row = jnp.split(
+                    row[5 * ops_pad:], cuts)
+                job = cost_model.JobParams(
+                    ops=ops, macro=cost_model.MacroParams(*macro),
+                    tech=cost_model.TechParams(*tech), allowed=allowed,
+                    obj_code=code[0].astype(jnp.int32), area_budget=jnp.inf,
+                    bw=cfg_row[5])
+                lat, en, idx, area, true_ops = \
+                    cost_model.workload_metrics_core(job, cfg_row)
+                return jnp.concatenate([jnp.stack([lat, en, area, true_ops]),
+                                        idx.astype(lat.dtype)])
+            return "finish_metrics", jax.jit(jax.vmap(finish_metrics))
 
         return self._cached(key, build)
 
@@ -1628,12 +1673,11 @@ class ExplorationEngine:
     def _finish(self, p: _PreparedJob, cfg: AcceleratorConfig, search: dict,
                 sa_res: SearchResult | None) -> ExploreResult:
         job = p.job
-        cfg_row = jnp.asarray(
-            [cfg.mr, cfg.mc, cfg.scr, cfg.is_kb, cfg.os_kb, cfg.bw],
-            dtype=float)
-        metrics = cost_model.workload_metrics(
-            p.workload.as_arrays(), cfg_row, job.macro, job.tech,
-            job.objective, job.strategy_set)
+        fn = self._finish_executable(p.ops_pad)
+        lat, en, area, true_ops, *idx = np.asarray(fn(_finish_row(p, cfg)))[0]
+        metrics = cost_model.metrics_dict(
+            lat, en, idx, area, true_ops, freq_mhz=job.macro.freq_mhz,
+            n_ops=len(p.workload.ops))
         per_op = {
             op.name or f"op{i}":
                 str(ALL_STRATEGIES[metrics["strategy_idx"][i]])

@@ -254,3 +254,110 @@ def test_snap_fallback_is_its_own_span_outside_finish():
     assert fin["args"]["parent"] == sp.span_id and "fallback" not in \
         fin["args"]
     assert fb["ts"] + fb["dur"] <= fin["ts"] + 1e-3
+
+
+# ------------------------------------------------------------------ #
+# the jitted result epilogue (finish_metrics)
+# ------------------------------------------------------------------ #
+def _two_width_jobs():
+    """Two networks whose merged operators pad to different widths (bert
+    5 ops -> 8, whisper-small 13 ops -> 16)."""
+    from repro.configs import get_arch
+    return [
+        ExploreJob(TPDCIM_MACRO, bert_large_workload(), 2.23,
+                   objective="ee", space=SMALL),
+        ExploreJob(get_macro("vanilla-dcim"),
+                   get_arch("whisper-small").workload(seq=512), 5.0,
+                   objective="th", strategy_set="so", space=SMALL),
+    ]
+
+
+@pytest.mark.parametrize("method", ["exhaustive", "sa"])
+def test_epilogue_matches_eager_workload_metrics(method):
+    from repro.core import cost_model
+    from repro.core.strategies import ALL_STRATEGIES
+    jobs = _two_width_jobs()
+    engine = ExplorationEngine()
+    assert {engine._prepare(j).ops_pad for j in jobs} == {8, 16}
+    results = engine.run(jobs, method=method,
+                         sa_settings=SASettings(n_chains=8, n_steps=40,
+                                                seed=2))
+    for job, r in zip(jobs, results):
+        wl = job.merged_workload()
+        c = r.config
+        eager = cost_model.workload_metrics(
+            wl.as_arrays(),
+            np.array([c.mr, c.mc, c.scr, c.is_kb, c.os_kb, c.bw], float),
+            job.macro, job.tech, job.objective, job.strategy_set)
+        idx = eager.pop("strategy_idx")
+        assert set(r.metrics) == set(eager)
+        for key, value in eager.items():
+            assert r.metrics[key] == pytest.approx(value, rel=1e-6), key
+        assert r.per_op_strategy == {
+            op.name or f"op{i}": str(ALL_STRATEGIES[idx[i]])
+            for i, op in enumerate(wl.ops)}
+
+
+@pytest.mark.parametrize("network", ["bert-large", "whisper-small"])
+def test_padded_rows_leave_workload_metrics_core_unchanged(network):
+    import jax
+
+    from repro.configs import get_arch
+    from repro.core import cost_model
+    from repro.core.engine import _job_arrays
+    wl = (bert_large_workload() if network == "bert-large"
+          else get_arch(network).workload(seq=512))
+    engine = ExplorationEngine()
+    p = engine._prepare(ExploreJob(TPDCIM_MACRO, wl, 3.0, space=SMALL))
+    n = len(p.workload.ops)
+    cfg_row = np.array([2.0, 2.0, 4.0, 16.0, 16.0, 256.0])
+    core = jax.jit(cost_model.workload_metrics_core)
+    outs = [core(_job_arrays(p._replace(ops_pad=width)), cfg_row)
+            for width in (n, 2 * n)]
+    (lat, en, idx, area, true_ops), padded = outs
+    assert idx.shape == (n,) and padded[2].shape == (2 * n,)
+    np.testing.assert_array_equal(padded[2][:n], idx)
+    for base, pad in ((lat, padded[0]), (en, padded[1]),
+                      (true_ops, padded[4])):
+        assert float(pad) == pytest.approx(float(base), rel=1e-6)
+    assert float(padded[3]) == float(area)
+    assert float(true_ops) == 2.0 * sum(
+        op.m * op.k * op.n * op.count for op in p.workload.ops)
+
+
+def test_epilogue_compiles_once_per_operator_bucket():
+    from repro.configs import get_arch
+    from repro.core.engine import _M_TRACES
+    counter = _M_TRACES.labels(executable="finish_metrics")
+    engine = ExplorationEngine()
+    bert = ExploreJob(TPDCIM_MACRO, bert_large_workload(), 2.23,
+                      space=SMALL)
+    yi = ExploreJob(get_macro("vanilla-dcim"),
+                    get_arch("yi-6b").workload(seq=512), 5.0, space=SMALL)
+    assert engine._prepare(bert).ops_pad == engine._prepare(yi).ops_pad == 8
+    engine.run([bert], method="exhaustive")
+    traced = counter.value
+    finish_keys = [k for k in engine._executables if k[0] == "finish"]
+    assert len(finish_keys) == 1
+    engine.run([bert], method="exhaustive")
+    engine.run([yi], method="exhaustive")
+    engine.run([bert, yi], method="exhaustive")
+    assert counter.value == traced
+    assert [k for k in engine._executables if k[0] == "finish"] == \
+        finish_keys
+
+
+def test_epilogue_module_is_not_a_cost_executable():
+    """The roofline readers sum every device module named ``one_job``;
+    the epilogue's must not be one of them."""
+    from repro.core.engine import _finish_row
+    from repro.core.template import AcceleratorConfig
+    engine = ExplorationEngine()
+    fn = engine._finish_executable(8).__wrapped__
+    assert fn.__name__ == "finish_metrics"
+    p = engine._prepare(ExploreJob(TPDCIM_MACRO, bert_large_workload(),
+                                   2.23, space=SMALL))
+    row = _finish_row(p, AcceleratorConfig(1, 1, 1, 2, 2, bw=256))
+    text = fn.lower(row).as_text()
+    assert "jit_finish_metrics" in text
+    assert "one_job" not in text

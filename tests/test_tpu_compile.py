@@ -136,3 +136,21 @@ def test_exhaustive_executable_compiles(one_chip, ops_pad, jobs):
     fn = engine._exhaustive_executable(ops_pad).__wrapped__
     compiled = fn.lower(specs, block).compile()
     assert compiled.as_text()
+
+
+@pytest.mark.parametrize("ops_pad", [8, 16])
+def test_finish_executable_compiles(one_chip, ops_pad):
+    """The engine's result epilogue at the Fig. 7 buckets: one job's
+    packed row, the shape ``_finish`` calls it with."""
+    from repro.core.engine import ExplorationEngine, ExploreJob, _finish_row
+    from repro.core.ir import bert_large_workload
+    from repro.core.macro import get_macro
+    from repro.core.template import AcceleratorConfig
+
+    engine = ExplorationEngine(persistent_compile_cache=False)
+    job = ExploreJob(get_macro("vanilla-dcim"), bert_large_workload(), 5.0)
+    p = engine._prepare(job)._replace(ops_pad=ops_pad)
+    row = _finish_row(p, AcceleratorConfig(1, 1, 1, 2, 2, bw=256))
+    fn = engine._finish_executable(ops_pad).__wrapped__
+    compiled = fn.lower(_spec(one_chip, row.shape)).compile()
+    assert "jit_finish_metrics" in compiled.as_text()
