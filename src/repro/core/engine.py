@@ -69,7 +69,7 @@ from repro.core.annealing import SASettings, _axes_matrix
 from repro.core.calibration import DEFAULT_TECH, TechConstants
 from repro.core.ir import Workload
 from repro.core.macro import MacroSpec
-from repro.core.pruning import DesignSpace, candidates_with_bw, prune_space
+from repro.core.pruning import DesignSpace, prune_rows
 from repro.core.strategies import ALL_STRATEGIES
 from repro.core.template import AcceleratorConfig, accelerator_area_mm2
 from repro.search.base import SearchResult, available_backends, get_backend
@@ -1602,12 +1602,12 @@ class ExplorationEngine:
                            ) -> tuple[np.ndarray, dict]:
         job = p.job
         with _phase("prune", job=p.key, **_fallback_arg(fallback)):
-            cands, stats = prune_space(
-                p.job.design_space(), job.macro, job.area_budget_mm2,
+            rows, stats = prune_rows(
+                job.design_space(), job.macro, job.area_budget_mm2,
                 job.bw, job.tech)
-            if len(cands) == 0:
+            if len(rows) == 0:
                 raise ValueError("no feasible hardware point under budget")
-            return candidates_with_bw(cands, job.bw), stats
+            return rows, stats
 
     def _sweep_values(
         self, ops_pad: int, stacked: cost_model.JobParams,

@@ -13,6 +13,11 @@ from repro.core import (
     get_macro,
 )
 from repro.core.macro import TPDCIM_MACRO, TRANCIM_MACRO
+from repro.core.template import (
+    AcceleratorConfig,
+    accelerator_area_mm2,
+    bandwidth_ok,
+)
 
 SMALL = DesignSpace(mr=(1, 2, 3), mc=(1, 2), scr=(1, 4, 16),
                     is_kb=(2, 16, 128), os_kb=(2, 16, 64))
@@ -125,6 +130,64 @@ def test_candidate_values_match_objective():
     best = engine.run([job], method="exhaustive")[0]
     np_best = rows[int(np.argmin(vals))]
     assert tuple(int(x) for x in np_best[:5]) == best.config.as_tuple()
+
+
+_PRUNE_STATS = ("raw", "kept", "bandwidth_pruned", "area_pruned",
+                "pruned_fraction")
+
+
+def test_cached_pruning_leaves_exhaustive_runs_unchanged():
+    """Jobs that share a design-space table but not a budget: the first
+    lookup builds the table and every later one hits it; the results
+    equal a run that builds the table anew, and the pruning an uncached
+    enumeration gives (its stats, and its argmin as the config)."""
+    import itertools
+
+    from repro.core.pruning import _M_TABLE, clear_space_tables
+
+    def counts():
+        return (_M_TABLE.value(outcome="hit"),
+                _M_TABLE.value(outcome="miss"))
+
+    def answer(r):
+        return (r.config.as_tuple(), r.metrics, r.per_op_strategy,
+                {k: r.search[k] for k in _PRUNE_STATS})
+
+    budgets = (2.0, 2.23, 2.6, 3.1, 4.0)
+    jobs = [ExploreJob(TPDCIM_MACRO, bert_large_workload(), b, space=SMALL)
+            for b in budgets]
+    engine = ExplorationEngine()
+    clear_space_tables()
+    hit0, miss0 = counts()
+    cached = engine.run(jobs, method="exhaustive")
+    assert counts() == (hit0 + len(jobs) - 1, miss0 + 1)
+    again = engine.run(jobs, method="exhaustive")
+    assert counts() == (hit0 + 2 * len(jobs) - 1, miss0 + 1)
+    clear_space_tables()
+    cold = engine.run(jobs, method="exhaustive")
+    assert [answer(r) for r in cached] == [answer(r) for r in again] == \
+        [answer(r) for r in cold]
+
+    raw = np.array(list(itertools.product(*SMALL.axes())), dtype=np.int64)
+    ref_rows = []
+    for job, res in zip(jobs, cached):
+        cfgs = [AcceleratorConfig(*map(int, row), bw=job.bw) for row in raw]
+        bw_ok = np.array([bandwidth_ok(c, job.macro) for c in cfgs])
+        area_ok = np.array([accelerator_area_mm2(c, job.macro)
+                            <= job.area_budget_mm2 for c in cfgs])
+        keep = bw_ok & area_ok
+        assert {k: res.search[k] for k in _PRUNE_STATS} == {
+            "raw": len(raw), "kept": int(keep.sum()),
+            "bandwidth_pruned": int((~bw_ok).sum()),
+            "area_pruned": int((bw_ok & ~area_ok).sum()),
+            "pruned_fraction": 1.0 - keep.sum() / len(raw)}
+        ref_rows.append(np.concatenate(
+            [raw[keep], np.full((int(keep.sum()), 1), job.bw)],
+            axis=1).astype(np.float64))
+    vals = engine.candidate_values(jobs, ref_rows)
+    for res, rows, v in zip(cached, ref_rows, vals):
+        assert res.config.as_tuple() == tuple(
+            int(x) for x in rows[int(np.argmin(v))][:5])
 
 
 # ------------------------------------------------------------------ #
